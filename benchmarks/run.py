@@ -1086,6 +1086,8 @@ SECTIONS = {
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", "--section", dest="only", default="",
                     help="comma-separated subset of sections")

@@ -75,8 +75,12 @@ def _decode_kernel(words_ref, phase_ref, table_ref, out_ref, *, bits, G, W,
             sl = jax.lax.rem(phase_ref[...] + j, n_slices)
             code = sl * rows + code                        # row in stacked table
         onehot = (code == tab_iota).astype(jnp.float32)    # (BG, n_tab)
+        # HIGHEST: a default-precision MXU pass would round the f32 table
+        # to bf16; the multi-pass product of an exact 0/1 one-hot
+        # reassembles every table entry bit-exactly
         feat = jax.lax.dot_general(                        # MXU gather
             onehot, table, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
         out_ref[:, j, :] = feat.astype(out_ref.dtype)
 

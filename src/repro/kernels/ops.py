@@ -1,7 +1,11 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels run with interpret=True; on TPU they
-compile natively. ``INTERPRET`` flips automatically from the backend.
+The kernel path is decided from the platform when a wrapper is CALLED
+(:func:`interpret_mode`), never when this module is imported: on TPU
+every kernel compiles through Mosaic, on CPU it runs in Pallas
+interpret mode (the encode wrapper takes the bit-identical jnp oracle
+there, see :func:`encode_codes`), and any other platform is an error —
+there is no silent fallback that could hide the device.
 
 Every dispatch runs under a ``jax.named_scope("octopus/<op>")`` so
 device traces (``jax.profiler``) attribute kernel time to the protocol
@@ -22,12 +26,23 @@ from .rmsnorm import rmsnorm_pallas
 from .selective_scan import selective_scan_pallas
 from .vq_nn import vq_nearest_pallas
 
-INTERPRET = jax.default_backend() != "tpu"
+
+def interpret_mode() -> bool:
+    """False on TPU (compiled kernels), True on CPU (interpret mode);
+    raises on any other platform."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"no kernel path for platform {backend!r}: the Pallas kernels "
+        f"compile for TPU and run in interpret mode on CPU only")
 
 
 def vq_nearest(z, codebook, **kw):
     """(N, M), (K, M) -> (N,) int32 nearest codebook atom per row."""
-    kw.setdefault("interpret", INTERPRET)
+    kw.setdefault("interpret", interpret_mode())
     if kw["interpret"]:
         # off-TPU there is no VMEM budget: fatter N blocks mean fewer
         # (traced) grid steps, which dominates interpret-mode runtime
@@ -39,14 +54,14 @@ def vq_nearest(z, codebook, **kw):
 def pack_codes(codes, *, bits, **kw):
     """Flat/any-shape int codes -> (n_groups, W) uint32 dense bit-stream
     at ceil(log2 K) bits per code (see kernels/pack_bits.py layout)."""
-    kw.setdefault("interpret", INTERPRET)
+    kw.setdefault("interpret", interpret_mode())
     with jax.named_scope("octopus/pack_codes"):
         return pack_codes_pallas(codes, bits=bits, **kw)
 
 
 def unpack_codes(words, *, bits, count, **kw):
     """(n_groups, W) uint32 words -> (count,) int32 codes, bit-exact."""
-    kw.setdefault("interpret", INTERPRET)
+    kw.setdefault("interpret", interpret_mode())
     with jax.named_scope("octopus/unpack_codes"):
         return unpack_codes_pallas(words, bits=bits, count=count, **kw)
 
@@ -80,7 +95,7 @@ def decode_codes(words, table, *, bits=None, count=None, n_slices=1,
         with jax.named_scope("octopus/decode_codes_ref"):
             return decode_codes_ref(words, table, bits=bits, count=count,
                                     n_slices=n_slices, phases=phases)
-    kw.setdefault("interpret", INTERPRET)
+    kw.setdefault("interpret", interpret_mode())
     with jax.named_scope("octopus/decode_codes"):
         return decode_codes_pallas(words, table, bits=bits, count=count,
                                    n_slices=n_slices, phases=phases, **kw)
@@ -94,18 +109,19 @@ def encode_codes(z, codebooks, *, bits, n_groups=1, n_slices=1,
     (N, K) distance matrix and the int32 index tensor never hit HBM (see
     kernels/encode_codes.py for modes and the record/packing layout).
 
-    ``use_ref``: None (default) runs the Pallas kernel on TPU and the
-    pure-jnp oracle (ref.encode_codes_ref) elsewhere — the oracle emits
-    bit-identical words, and unlike the other wrappers' interpret
-    fallback it keeps CPU CI fast (the XLA-fused oracle beats the
-    interpreted grid). True/False force the oracle/kernel; off-TPU the
+    ``use_ref``: None (default) runs the compiled Pallas kernel on TPU
+    and the pure-jnp oracle (ref.encode_codes_ref) on CPU — the oracle
+    emits bit-identical words, and unlike the other wrappers' interpret
+    mode it keeps CPU CI fast (the XLA-fused oracle beats the
+    interpreted grid). True/False force the oracle/kernel; on CPU the
     forced kernel runs with interpret=True."""
-    if use_ref or (use_ref is None and INTERPRET):
+    interpret = interpret_mode()
+    if use_ref or (use_ref is None and interpret):
         from .ref import encode_codes_ref
         with jax.named_scope("octopus/encode_codes_ref"):
             return encode_codes_ref(z, codebooks, bits=bits,
                                     n_groups=n_groups, n_slices=n_slices)
-    kw.setdefault("interpret", INTERPRET)
+    kw.setdefault("interpret", interpret)
     if kw["interpret"]:
         # off-TPU there is no VMEM budget: fatter N blocks mean fewer
         # (traced) grid steps, which dominates interpret-mode runtime
@@ -137,7 +153,7 @@ def encode_payload(z, codebooks, *, bits, shape, n_groups=1, n_slices=1,
 
 def flash_attention(q, k, v, *, causal=True, window=0, **kw):
     """(B,T,Hq,D) with GQA k/v (B,T,Hkv,D): repeat kv then run the kernel."""
-    kw.setdefault("interpret", INTERPRET)
+    kw.setdefault("interpret", interpret_mode())
     q_per_kv = q.shape[2] // k.shape[2]
     if q_per_kv > 1:
         k = jnp.repeat(k, q_per_kv, axis=2)
@@ -146,11 +162,11 @@ def flash_attention(q, k, v, *, causal=True, window=0, **kw):
 
 
 def rmsnorm(x, scale, *, eps=1e-6, **kw):
-    kw.setdefault("interpret", INTERPRET)
+    kw.setdefault("interpret", interpret_mode())
     return rmsnorm_pallas(x, scale, eps=eps, **kw)
 
 
 def selective_scan(decay, inp, c, h0, **kw):
     """Fused Mamba recurrence + output contraction (see selective_scan.py)."""
-    kw.setdefault("interpret", INTERPRET)
+    kw.setdefault("interpret", interpret_mode())
     return selective_scan_pallas(decay, inp, c, h0, **kw)

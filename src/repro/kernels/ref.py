@@ -101,16 +101,12 @@ def decode_codes_ref(words, table, *, bits: int, count: int,
     return table[codes[:count]]
 
 
-def encode_codes_ref(z, codebooks, *, bits: int, n_groups: int = 1,
-                     n_slices: int = 1):
-    """(R, P, M) latents + (R, K, M) per-record codebooks ->
-    (words (R*nW, W) uint32, counts (R, K), sums (R, K, M)).
+def encode_scores_ref(z, codebooks, *, n_groups: int = 1, n_slices: int = 1):
+    """The scores the encode argmin runs over, lowest wins.
 
-    Unfused oracle for kernels/encode_codes.py: per record, quantize
-    against that record's codebook (plain-VQ score ``||e||^2 - 2 z.e^T``
-    or the GSVQ Eq. 2 group match), pack each record's codes into its own
-    zero-padded word stream, and segment-sum the Eq. 7-8 EMA statistics
-    onto representative atoms (``g*ng + ng//2``; plain VQ: the atom).
+    Plain VQ: ``||e||^2 - 2 z.e^T`` per atom, (R, P, K). GSVQ: the Eq. 2
+    per-slice group match — sqrt per-atom distances mean-pooled over
+    each group — as (R, P, n_slices, n_groups).
     """
     R, P, M = z.shape
     K = codebooks.shape[1]
@@ -127,18 +123,37 @@ def encode_codes_ref(z, codebooks, *, bits: int, n_groups: int = 1,
             e2 = jnp.sum(cb_s * cb_s, -1)[None, :]
             d2 = jnp.maximum(z2 - 2.0 * (z_s @ cb_s.T) + e2, 0.0)
             d = jnp.sqrt(d2 + 1e-12)
-            gd = jnp.mean(d.reshape(-1, n_groups, ng), axis=-1)
-            return jnp.argmin(gd, axis=-1).astype(jnp.int32)
+            return jnp.mean(d.reshape(-1, n_groups, ng), axis=-1)
 
-        idx = jax.vmap(jax.vmap(per_slice, in_axes=(1, 0), out_axes=1))(
-            zsl, csl)                                   # (R, P, S)
+        return jax.vmap(jax.vmap(per_slice, in_axes=(1, 0), out_axes=1))(
+            zsl, csl)                                   # (R, P, S, G)
+    e2 = jnp.sum(cb * cb, -1)                           # (R, K)
+    cross = jnp.einsum("rpm,rkm->rpk", zf, cb)
+    return e2[:, None, :] - 2.0 * cross
+
+
+def encode_codes_ref(z, codebooks, *, bits: int, n_groups: int = 1,
+                     n_slices: int = 1):
+    """(R, P, M) latents + (R, K, M) per-record codebooks ->
+    (words (R*nW, W) uint32, counts (R, K), sums (R, K, M)).
+
+    Unfused oracle for kernels/encode_codes.py: per record, quantize
+    against that record's codebook (the argmin of
+    :func:`encode_scores_ref`), pack each record's codes into its own
+    zero-padded word stream, and segment-sum the Eq. 7-8 EMA statistics
+    onto representative atoms (``g*ng + ng//2``; plain VQ: the atom).
+    """
+    R, P, M = z.shape
+    K = codebooks.shape[1]
+    zf = z.astype(jnp.float32)
+    idx = jnp.argmin(encode_scores_ref(z, codebooks, n_groups=n_groups,
+                                       n_slices=n_slices),
+                     axis=-1).astype(jnp.int32)         # (R, P[, S])
+    if n_groups > 1 or n_slices > 1:
+        ng = K // n_groups
         rep = idx * ng + ng // 2
         votes = jnp.broadcast_to(zf[:, :, None, :], idx.shape + (M,))
     else:
-        e2 = jnp.sum(cb * cb, -1)                       # (R, K)
-        cross = jnp.einsum("rpm,rkm->rpk", zf, cb)
-        idx = jnp.argmin(e2[:, None, :] - 2.0 * cross,
-                         axis=-1).astype(jnp.int32)     # (R, P)
         rep = idx
         votes = zf
     counts = jax.vmap(lambda r: jax.ops.segment_sum(

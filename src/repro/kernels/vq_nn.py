@@ -30,13 +30,16 @@ BLOCK_N = 256
 BLOCK_K = 512
 
 
-def _vq_nn_kernel(z_ref, e_ref, idx_ref, best_ref, bestidx_ref, *, block_k):
+def _vq_nn_kernel(z_ref, e_ref, e2_ref, idx_ref, best_ref, bestidx_ref, *,
+                  block_k):
     """One (n_block, k_block) tile.
 
     z_ref:   (BLOCK_N, M) queries            [VMEM]
     e_ref:   (BLOCK_K, M) codebook tile      [VMEM]
-    idx_ref: (BLOCK_N,)   output indices     [VMEM] (written on last k step)
-    best_ref/bestidx_ref: VMEM scratch carries across the K grid axis.
+    e2_ref:  (1, BLOCK_K) its squared norms  [VMEM]
+    idx_ref: (BLOCK_N, 1) output indices     [VMEM] (written on last k step)
+    best_ref/bestidx_ref: (BLOCK_N, 1) VMEM scratch carried across the K
+    grid axis.
     """
     kstep = pl.program_id(1)
     nk = pl.num_programs(1)
@@ -49,14 +52,16 @@ def _vq_nn_kernel(z_ref, e_ref, idx_ref, best_ref, bestidx_ref, *, block_k):
     z = z_ref[...].astype(jnp.float32)                    # (N, M)
     e = e_ref[...].astype(jnp.float32)                    # (K_blk, M)
     # distance sans ||z||^2 (row-constant): ||e||^2 - 2 z e^T
-    e2 = jnp.sum(e * e, axis=-1)[None, :]                 # (1, K_blk)
+    e2 = e2_ref[...]                                      # (1, K_blk)
     cross = jax.lax.dot_general(                          # MXU matmul
         z, e, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,              # no bf16 rounding
         preferred_element_type=jnp.float32)               # (N, K_blk)
     d = e2 - 2.0 * cross
 
-    local_best = jnp.min(d, axis=-1)                      # (N,)
-    local_arg = jnp.argmin(d, axis=-1).astype(jnp.int32) + kstep * block_k
+    local_best = jnp.min(d, axis=-1, keepdims=True)       # (N, 1)
+    local_arg = (jnp.argmin(d, axis=-1, keepdims=True).astype(jnp.int32)
+                 + kstep * block_k)
 
     prev_best = best_ref[...]
     prev_idx = bestidx_ref[...]
@@ -88,6 +93,9 @@ def vq_nearest_pallas(z, codebook, *, block_n: int = BLOCK_N,
     ep = jnp.pad(codebook, ((0, pad_k), (0, 0)), constant_values=1e30) \
         if pad_k else codebook
     Np, Kp = N + pad_n, K + pad_k
+    # lane-major row norms: reducing the tile in-kernel would need a
+    # sublane -> lane relayout per grid step
+    e2 = jnp.sum(ep.astype(jnp.float32) ** 2, axis=-1)[None, :]
 
     grid = (Np // block_n, Kp // block_k)
     out = pl.pallas_call(
@@ -96,13 +104,14 @@ def vq_nearest_pallas(z, codebook, *, block_n: int = BLOCK_N,
         in_specs=[
             pl.BlockSpec((block_n, M), lambda n, k: (n, 0)),
             pl.BlockSpec((block_k, M), lambda n, k: (k, 0)),
+            pl.BlockSpec((1, block_k), lambda n, k: (0, k)),
         ],
-        out_specs=pl.BlockSpec((block_n,), lambda n, k: (n,)),
-        out_shape=jax.ShapeDtypeStruct((Np,), jnp.int32),
+        out_specs=pl.BlockSpec((block_n, 1), lambda n, k: (n, 0)),
+        out_shape=jax.ShapeDtypeStruct((Np, 1), jnp.int32),
         scratch_shapes=[
-            pltpu.VMEM((block_n,), jnp.float32),
-            pltpu.VMEM((block_n,), jnp.int32),
+            pltpu.VMEM((block_n, 1), jnp.float32),
+            pltpu.VMEM((block_n, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(zp, ep)
-    return out[:N]
+    )(zp, ep, e2)
+    return out[:N, 0]

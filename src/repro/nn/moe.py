@@ -105,7 +105,6 @@ def _moe_shardmap(params, cfg, x, mesh, dp_axes, activation) -> MoEOut:
     (~tokens x d), versus the full dispatch-buffer all-reduce XLA emits
     for the scatter formulation (measured 18.8-37.6 GB/op on DeepSeek).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     m = cfg.moe
@@ -156,12 +155,12 @@ def _moe_shardmap(params, cfg, x, mesh, dp_axes, activation) -> MoEOut:
         return y.reshape(xb.shape), aux
 
     e = params["experts"]
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp_axes, None, None), P(), P("model", None, None),
                   P("model", None, None), P("model", None, None)),
         out_specs=(P(dp_axes, None, None), P()),
-        check_rep=False)
+        check_vma=False)
     y, aux = fn(x, params["router"], e["wi"], e["wg"], e["wo"])
 
     if "shared" in params:

@@ -129,18 +129,20 @@ class SimEngine:
 
         round_fn = _round
         if mesh is not None:
-            from jax.experimental.shard_map import shard_map
+            from jax.sharding import Mesh, NamedSharding
             from jax.sharding import PartitionSpec as P
             spec = P("data")
-            step = shard_map(step, mesh, in_specs=(spec, spec),
-                             out_specs=(spec, spec), check_rep=False)
+            step = jax.shard_map(step, mesh=mesh, in_specs=(spec, spec),
+                                 out_specs=(spec, spec), check_vma=False)
             # the WHOLE round — encode, fused dispatch, EMA — runs inside
             # the shard-mapped body, so the kernel sees only its shard's
             # clients; per-shard payloads are per-client record streams,
             # so concatenating them along rows IS the population payload
-            round_fn = shard_map(_round, mesh, in_specs=(spec, spec),
-                                 out_specs=(spec, spec), check_rep=False)
-
+            round_fn = jax.shard_map(_round, mesh=mesh,
+                                     in_specs=(spec, spec),
+                                     out_specs=(spec, spec), check_vma=False)
+            self._uplink_sharding = NamedSharding(
+                Mesh(mesh.devices.flat[:1], ("uplink",)), P())
         self._step = step
         self._step_jit = jax.jit(step)
         self._round = jax.jit(round_fn)
@@ -170,6 +172,13 @@ class SimEngine:
         assert data.shape[0] == c, (data.shape, c)
         idx_shape = self._index_shape(clients, data)
         clients, payload = self._round(clients, data)
+        if self.mesh is not None:
+            # the uplink leaves the client shards: gather the per-shard
+            # record streams onto the mesh's first device, where the
+            # server's unpack / decode kernels run (a pallas_call takes no
+            # sharded operand). A one-device mesh, because a plain
+            # device_put keeps an Explicit mesh axis in the array's type.
+            payload = jax.device_put(payload, self._uplink_sharding)
         return clients, CodePayload(
             payload=payload, bits=self.bits, shape=idx_shape, n_records=c,
             version=int(version),

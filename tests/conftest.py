@@ -17,12 +17,8 @@ def pytest_configure(config):
 
 
 def abstract_mesh(sizes, names):
-    """AbstractMesh across jax versions: new (sizes, names) signature vs
-    the 0.4.x ((name, size), ...) pair tuple."""
-    try:
-        return jax.sharding.AbstractMesh(sizes, names)
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(names, sizes)))
+    """A device-free mesh of the given axis sizes and names."""
+    return jax.sharding.AbstractMesh(sizes, names)
 
 
 @pytest.fixture
