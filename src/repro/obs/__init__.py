@@ -7,6 +7,9 @@ Opt-in tracing of every uplink from encode dispatch to codebook merge:
     with obs.recording("trace.jsonl"):
         client.round(batch)            # every layer logs to the trace
 
+    with obs.span("cohort/pull"):      # octopus/cohort/pull on the
+        host = np.asarray(x)           # jax.profiler timeline
+
     with obs.dispatch_monitor() as counts:
         client.round(batch)
     assert (counts.encoder_passes, counts.encode_dispatches) == (1, 1)
@@ -18,11 +21,12 @@ traces the unmodified examples). Summaries: ``python -m repro.obs.report
 trace.jsonl``. See ``recorder.py`` for the event schema and the §2.5
 metadata-only capture rule.
 """
-from .metrics import (Counter, DispatchCounts, Gauge, Histogram,
-                      MetricsRegistry, dispatch_monitor)
+from .metrics import (Counter, DispatchCounts, Gauge, MetricsRegistry,
+                      dispatch_monitor)
 from .recorder import (ENV_VAR, EVENT_KINDS, PAYLOAD_META_FIELDS,
-                       FlightRecorder, active, install, install_from_env,
-                       payload_meta, recording, uninstall)
+                       SPAN_PREFIX, FlightRecorder, active, install,
+                       install_from_env, payload_meta, recording, span,
+                       uninstall)
 
 __all__ = [
     "Counter",
@@ -31,15 +35,16 @@ __all__ = [
     "EVENT_KINDS",
     "FlightRecorder",
     "Gauge",
-    "Histogram",
     "MetricsRegistry",
     "PAYLOAD_META_FIELDS",
+    "SPAN_PREFIX",
     "active",
     "dispatch_monitor",
     "install",
     "install_from_env",
     "payload_meta",
     "recording",
+    "span",
     "uninstall",
 ]
 

@@ -1,4 +1,4 @@
-"""Metrics plane: counters / gauges / histograms + the dispatch monitor.
+"""Metrics plane: counters / gauges + the dispatch monitor.
 
 The registry is deliberately tiny — plain Python floats behind names —
 because it runs INSIDE the serving path: ``repro.wire`` /
@@ -9,8 +9,9 @@ round while a flight recorder is active. Standard instruments:
              ``wire_bytes``, ``merges``)
   gauge      last-written level (``uplink_queue_depth``,
              ``store_records``, ``store_bytes``)
-  histogram  streaming count/total/min/max (+mean) of an observation
-             (``round_ms``, ``decode_ms/v<version>``)
+
+Durations are not kept here: each timed event carries its ``dur_ms``,
+and ``repro.obs.span`` puts the blocks on the profiler's timeline.
 
 :func:`dispatch_monitor` promotes the dispatch-counting trick that
 tests/test_encode.py and tests/test_wire.py (and the ``wire`` /
@@ -45,40 +46,12 @@ class Gauge:
         self.value = v
 
 
-class Histogram:
-    __slots__ = ("count", "total", "min", "max")
-
-    def __init__(self):
-        self.count = 0
-        self.total = 0.0
-        self.min = float("inf")
-        self.max = float("-inf")
-
-    def observe(self, v: float) -> None:
-        self.count += 1
-        self.total += v
-        if v < self.min:
-            self.min = v
-        if v > self.max:
-            self.max = v
-
-    @property
-    def mean(self) -> float:
-        return self.total / self.count if self.count else 0.0
-
-    def as_dict(self) -> Dict[str, float]:
-        return {"count": self.count, "total": self.total, "mean": self.mean,
-                "min": self.min if self.count else 0.0,
-                "max": self.max if self.count else 0.0}
-
-
 class MetricsRegistry:
     """Name -> instrument, created on first touch."""
 
     def __init__(self):
         self.counters: Dict[str, Counter] = {}
         self.gauges: Dict[str, Gauge] = {}
-        self.histograms: Dict[str, Histogram] = {}
 
     # ---------------------------------------------------------- instruments
 
@@ -94,12 +67,6 @@ class MetricsRegistry:
             g = self.gauges[name] = Gauge()
         return g
 
-    def histogram(self, name: str) -> Histogram:
-        h = self.histograms.get(name)
-        if h is None:
-            h = self.histograms[name] = Histogram()
-        return h
-
     # ----------------------------------------------------------- shorthand
 
     def inc(self, name: str, v: float = 1.0) -> None:
@@ -108,16 +75,11 @@ class MetricsRegistry:
     def set_gauge(self, name: str, v: float) -> None:
         self.gauge(name).set(v)
 
-    def observe(self, name: str, v: float) -> None:
-        self.histogram(name).observe(v)
-
     def snapshot(self) -> Dict[str, Any]:
         """Plain-dict view (what the report CLI embeds in its JSON)."""
         return {
             "counters": {k: c.value for k, c in sorted(self.counters.items())},
             "gauges": {k: g.value for k, g in sorted(self.gauges.items())},
-            "histograms": {k: h.as_dict()
-                           for k, h in sorted(self.histograms.items())},
         }
 
 

@@ -45,9 +45,14 @@ disabled path. Instrumentation never touches RNG streams and never
 forces a different computation, so traced and untraced runs are
 bit-identical (pinned by tests/test_obs.py).
 
-Spans are plain events carrying ``dur_ms`` (and a ``span`` id when
-nesting matters); :meth:`FlightRecorder.span` times a ``with`` block and
-emits the event at exit.
+Spans: :func:`span` is the one span API. ``with obs.span("cohort/pull"):``
+opens a ``jax.profiler.TraceAnnotation`` named ``octopus/cohort/pull``,
+so under a profiler session the block shows on the host timeline beside
+the device ops it waits on, on the same clock; with no session running
+it costs the annotation and nothing else. Given ``event=<kind>`` and an
+installed recorder, the span also writes that event at exit with the
+block's host ``dur_ms`` (:meth:`FlightRecorder.span` is the same path).
+A span never waits on the device.
 """
 from __future__ import annotations
 
@@ -56,6 +61,8 @@ import os
 import threading
 import time
 from typing import IO, Any, Dict, Optional, Union
+
+from jax.profiler import TraceAnnotation
 
 from .metrics import MetricsRegistry
 
@@ -86,24 +93,64 @@ def payload_meta(payload) -> Dict[str, Any]:
     }
 
 
+SPAN_PREFIX = "octopus/"
+
+
+def _refuse_non_scalars(what: str, fields: Dict[str, Any]) -> None:
+    """Arrays and containers are refused outright, so no event or span —
+    present or future — can smuggle packed words, label vectors or
+    latents into a trace (§2.5 is enforced mechanically, not by
+    call-site discipline)."""
+    for k, v in fields.items():
+        if (isinstance(v, (list, tuple, set, dict, bytes, bytearray))
+                or getattr(v, "ndim", 0)):
+            raise ValueError(
+                f"{what} field {k!r} carries a {type(v).__name__}; events "
+                f"are scalar-only — the observability plane never records "
+                f"words, labels or latents (§2.5)")
+
+
 class _Span:
-    """Times a ``with`` block; emits ONE event (kind + dur_ms) at exit."""
+    """A ``with`` block on the profiler's clock (``octopus/<name>``) and,
+    when ``rec`` is given, ONE ``kind`` event with the block's ``dur_ms``
+    at exit."""
 
-    __slots__ = ("_rec", "_kind", "_fields", "_t0")
+    __slots__ = ("_ann", "_rec", "_kind", "_fields", "_t0")
 
-    def __init__(self, rec: "FlightRecorder", kind: str, fields: dict):
+    def __init__(self, name: str, rec: Optional["FlightRecorder"],
+                 kind: Optional[str], args: Dict[str, Any]):
+        _refuse_non_scalars(f"span {name!r}", args)
+        self._ann = TraceAnnotation(SPAN_PREFIX + name, **args)
         self._rec = rec
         self._kind = kind
-        self._fields = fields
+        self._fields = args
+
+    @property
+    def recording(self) -> bool:
+        """Whether the span writes a recorder event at exit."""
+        return self._rec is not None
+
+    def add(self, **fields) -> None:
+        """Event fields known only inside the block (a payload's metadata
+        after its dispatch). They go to the recorder's event; the
+        annotation's args were fixed at entry."""
+        if self._rec is not None:
+            self._fields.update(fields)
 
     def __enter__(self) -> "_Span":
-        self._t0 = time.perf_counter()
+        self._ann.__enter__()
+        if self._rec is not None:
+            self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
-        self._rec.event(self._kind,
-                        dur_ms=(time.perf_counter() - self._t0) * 1e3,
-                        **self._fields)
+        try:
+            if self._rec is not None:
+                self._rec.event(self._kind,
+                                dur_ms=(time.perf_counter() - self._t0) * 1e3,
+                                **self._fields)
+        finally:
+            self._ann.__exit__(*exc)
 
 
 class FlightRecorder:
@@ -114,8 +161,8 @@ class FlightRecorder:
     index>, ...fields}``. The writer flushes per event so a crashed or
     killed run keeps everything recorded up to the failure. A
     :class:`~repro.obs.metrics.MetricsRegistry` rides along
-    (``.metrics``) for the counters/gauges/histograms the instrumented
-    sites maintain while the recorder is active.
+    (``.metrics``) for the counters and gauges the instrumented sites
+    maintain while the recorder is active.
     """
 
     def __init__(self, path: Union[str, os.PathLike, IO[str]], *,
@@ -137,20 +184,10 @@ class FlightRecorder:
     def event(self, kind: str, **fields) -> Dict[str, Any]:
         """Emit one event; returns the dict that was written.
 
-        Field values must be SCALARS (numbers / strings / bools / None):
-        arrays and containers are refused outright, so no event kind —
-        present or future — can smuggle packed words, label vectors or
-        latents into a trace (§2.5 is enforced mechanically, not by
-        call-site discipline).
+        Field values must be SCALARS (numbers / strings / bools / None);
+        arrays and containers raise ``ValueError`` (§2.5).
         """
-        for k, v in fields.items():
-            if (isinstance(v, (list, tuple, set, dict, bytes, bytearray))
-                    or getattr(v, "ndim", 0)):
-                raise ValueError(
-                    f"trace event {kind!r} field {k!r} carries a "
-                    f"{type(v).__name__}; events are scalar-only — the "
-                    f"observability plane never records words, labels or "
-                    f"latents (§2.5)")
+        _refuse_non_scalars(f"trace event {kind!r}", fields)
         ev = {"kind": kind, "ts": time.time()}
         ev.update(fields)
         with self._lock:
@@ -163,8 +200,9 @@ class FlightRecorder:
 
     def span(self, kind: str, **fields) -> _Span:
         """``with rec.span("decode", version=3): ...`` — one event with
-        the block's ``dur_ms`` at exit."""
-        return _Span(self, kind, fields)
+        the block's ``dur_ms`` at exit, on this recorder; the block is
+        ``octopus/<kind>`` on the profiler's timeline too."""
+        return _Span(kind, self, kind, fields)
 
     def uplink(self, payload, **fields) -> Dict[str, Any]:
         """THE uplink event: one payload crossing the wire. Captures the
@@ -225,6 +263,15 @@ def uninstall() -> Optional[FlightRecorder]:
     global _ACTIVE
     rec, _ACTIVE = _ACTIVE, None
     return rec
+
+
+def span(name: str, *, event: Optional[str] = None, **args) -> _Span:
+    """``with obs.span("cohort", event="encode", cohort=i) as s: ...`` —
+    the block as ``octopus/<name>`` on the profiler's timeline, with the
+    scalar ``args`` as its stats; with ``event`` given and a recorder
+    installed, also one ``event`` with the block's ``dur_ms``, the
+    ``args`` and whatever ``s.add(...)`` added."""
+    return _Span(name, _ACTIVE if event is not None else None, event, args)
 
 
 class _Recording:

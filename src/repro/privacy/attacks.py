@@ -146,8 +146,6 @@ def shadow_attack(key, features, labels, n_classes: int, *,
                   advantage=report.advantage,
                   n_train=report.n_train, n_test=report.n_test,
                   n_classes=report.n_classes)
-        rec.metrics.observe(f"attack_advantage/{report.attack}",
-                            report.advantage)
     return report
 
 

@@ -439,7 +439,6 @@ class ContinuousIngestService:
                       bytes_in_flight=self.queue.bytes_in_flight,
                       merged_version=merged_version, dur_ms=dur_ms,
                       **(extra_fields or {}))
-            rec.metrics.observe("tick_ms", dur_ms)
         self._tick_offered = 0
         self._tick_bytes = 0
         self.tick_idx += 1
@@ -468,7 +467,6 @@ class ContinuousIngestService:
                 rec.event("decode", version=int(v), dur_ms=dur_ms,
                           n_records=len(recs),
                           n_samples=int(sum(b.shape[0] for b in blocks)))
-                rec.metrics.observe(f"decode_ms/v{int(v)}", dur_ms)
             n_decoded += len(recs)
         self.decoded_records += n_decoded
         self.decode_dispatches += len(by_key)
@@ -805,7 +803,6 @@ class AsyncCodeServer:
                       queue_depth=len(self.queue),
                       bytes_in_flight=self.queue.bytes_in_flight,
                       merged_version=merged_version, dur_ms=dur_ms)
-            rec.metrics.observe("round_ms", dur_ms)
         return stats
 
     def _merge(self) -> int:

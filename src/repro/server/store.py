@@ -466,7 +466,6 @@ def decode_records(records, cfg: DVQAEConfig,
             ob.event("decode", version=int(v), dur_ms=dur_ms,
                      n_records=len(idxs),
                      n_samples=int(sum(b.shape[0] for b in blocks)))
-            ob.metrics.observe(f"decode_ms/v{int(v)}", dur_ms)
         for i, f in zip(idxs, blocks):
             feats_parts[i] = f
     feats = jnp.concatenate([feats_parts[i] for i, _ in recs], axis=0)

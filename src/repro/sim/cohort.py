@@ -54,7 +54,6 @@ from __future__ import annotations
 import time
 from typing import Callable, List, NamedTuple, Optional, Tuple
 
-import jax
 import numpy as np
 
 from repro.core import octopus as OC
@@ -184,32 +183,44 @@ class CohortEngine:
         from ``server``; per-cohort payloads are stamped ``version``.
         ``round_idx`` only labels the flight recorder's per-cohort
         encode events (the computation never reads it).
+
+        Each cohort runs inside the span ``octopus/cohort`` and its four
+        children in order: ``cohort/deploy`` (fresh clients, labels,
+        data), ``cohort/dispatch`` (enqueue the jitted round),
+        ``cohort/pull`` (codebooks and EMA counts to the host: the only
+        wait on the device) and ``cohort/fold`` (into the accumulator).
         """
         K, M = server.params["codebook"].shape
         stats = merge_stats_zero(int(K), int(M))
         payloads: List[CodePayload] = []
-        for cohort in plan.cohorts:
-            rec = _obs.active()
-            t0 = time.perf_counter() if rec is not None else 0.0
-            clients = self.engine.init_clients(server, int(cohort.size))
-            labels = labels_fn(cohort) if labels_fn is not None else None
-            clients, payload = self.engine.round(
-                clients, data_fn(cohort), version=version, labels=labels)
-            # fold this cohort's Step-5 contribution in; per-client
-            # fixed-point quantization is grouping-independent, so the
-            # integer totals match the single-shot population merge
-            stats = merge_stats_add(stats, merge_stats(
-                np.asarray(clients.params["codebook"]),
-                np.asarray(clients.ema.counts)))
-            payloads.append(payload)
-            if rec is not None:
-                jax.block_until_ready(payload.payload)
-                fields = {"cohort_size": int(cohort.size)}
-                if round_idx is not None:
-                    fields["round"] = int(round_idx)
-                rec.event("encode",
-                          dur_ms=(time.perf_counter() - t0) * 1e3,
-                          **fields, **_obs.payload_meta(payload))
+        for i, cohort in enumerate(plan.cohorts):
+            with _obs.span("cohort", event="encode", cohort=i,
+                           clients=int(cohort.size),
+                           version=int(version)) as span:
+                with _obs.span("cohort/deploy"):
+                    clients = self.engine.init_clients(server,
+                                                       int(cohort.size))
+                    labels = None if labels_fn is None else labels_fn(cohort)
+                    data = data_fn(cohort)
+                with _obs.span("cohort/dispatch"):
+                    clients, payload = self.engine.round(
+                        clients, data, version=version, labels=labels)
+                # the one place the host waits on the device
+                with _obs.span("cohort/pull"):
+                    codebooks = np.asarray(clients.params["codebook"])
+                    counts = np.asarray(clients.ema.counts)
+                # fold this cohort's Step-5 contribution in; per-client
+                # fixed-point quantization is grouping-independent, so the
+                # integer totals match the single-shot population merge
+                with _obs.span("cohort/fold"):
+                    stats = merge_stats_add(stats,
+                                            merge_stats(codebooks, counts))
+                payloads.append(payload)
+                if span.recording:
+                    fields = {"cohort_size": int(cohort.size)}
+                    if round_idx is not None:
+                        fields["round"] = int(round_idx)
+                    span.add(**fields, **_obs.payload_meta(payload))
         return CohortRound(payloads=tuple(payloads), stats=stats,
                            n_clients=plan.n_clients,
                            nbytes=sum(p.nbytes for p in payloads))
@@ -280,7 +291,6 @@ class CohortEngine:
                           bytes_delivered=delivered,
                           queue_depth=len(queue),
                           merged_version=merged_version, dur_ms=dur_ms)
-                rec.metrics.observe("round_ms", dur_ms)
                 rec.metrics.set_gauge("uplink_queue_depth", len(queue))
         return history
 

@@ -468,7 +468,6 @@ class OctopusServer:
             dur_ms = (time.perf_counter() - t0) * 1e3
             rec.event("decode", version=int(p.version), dur_ms=dur_ms,
                       n_samples=int(out.shape[0]))
-            rec.metrics.observe(f"decode_ms/v{int(p.version)}", dur_ms)
         return out
 
     # ----------------------------------------------------------- migration
@@ -579,9 +578,12 @@ class OctopusServer:
         round cohort-by-cohort and folds each cohort's fixed-point
         contribution into one accumulator; this finishes the merge and
         registers the new dictionary version. Bit-identical for any
-        cohort partition/order of the same client set."""
-        self.state = OC.server_merge_stats(self.state, stats)
-        version = self.registry.register(self.state.params["codebook"])
+        cohort partition/order of the same client set. The merge and the
+        registration run inside the span ``octopus/server/merge``, whose
+        ``version`` is the version merged from."""
+        with _obs.span("server/merge", version=int(self.version)):
+            self.state = OC.server_merge_stats(self.state, stats)
+            version = self.registry.register(self.state.params["codebook"])
         rec = _obs.active()
         if rec is not None:
             rec.metrics.inc("merges")

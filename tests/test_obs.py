@@ -8,6 +8,7 @@ match the PR-4/PR-5 regression baselines. The recorder itself must obey
 §2.5 — packed words, labels and latents never enter the trace, only
 payload METADATA.
 """
+import glob
 import json
 
 import jax
@@ -339,13 +340,9 @@ def test_metrics_registry_instruments():
     m.inc("uplinks", 3)
     m.inc("uplinks")
     m.set_gauge("depth", 7)
-    for v in (2.0, 4.0, 6.0):
-        m.observe("ms", v)
     snap = m.snapshot()
     assert snap["counters"]["uplinks"] == 4
     assert snap["gauges"]["depth"] == 7
-    h = snap["histograms"]["ms"]
-    assert (h["count"], h["min"], h["max"], h["mean"]) == (3, 2.0, 6.0, 4.0)
 
 
 def test_queue_and_store_metrics(tiny_cfg, server, data, tmp_path):
@@ -364,3 +361,108 @@ def test_queue_and_store_metrics(tiny_cfg, server, data, tmp_path):
     kinds = [e["kind"] for e in events]
     assert kinds.count("uplink") == 2     # facade round + queue.send
     assert "ingest" in kinds
+
+
+# ------------------------------------------------------------ program spans
+
+COHORT_CHILDREN = ("deploy", "dispatch", "pull", "fold")
+
+
+def _host_spans(directory):
+    """(name, start_ns, end_ns, stats) of every ``octopus/`` and
+    ``bench/`` host event in the newest profile under ``directory``."""
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(f"{directory}/**/*.xplane.pb", recursive=True))
+    data = ProfileData.from_file(path[-1])
+    return sorted(((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                    dict(e.stats))
+                   for plane in data.planes if plane.name.startswith("/host:")
+                   for line in plane.lines for e in line.events
+                   if e.name.startswith((obs.SPAN_PREFIX, "bench/"))),
+                  key=lambda s: s[1])
+
+
+def test_cohort_round_spans_on_the_profiler_clock(tiny_cfg, server, data,
+                                                  tmp_path):
+    """Under a profiler session each cohort is one ``octopus/cohort``
+    span holding deploy, dispatch, pull and fold once each, in order;
+    the server merge is one ``octopus/server/merge``; and the round's
+    outputs are bit-identical to an unprofiled round."""
+    engine = CohortEngine(tiny_cfg, gamma=0.9, n_local_steps=0)
+    plan = CohortPlan.build(np.arange(N_CLIENTS), 5)
+    plain = engine.round(server, plan, _data_fn(data), version=0)
+    wire = OctopusServer(server, tiny_cfg)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("bench/window"):
+            traced = engine.round(server, plan, _data_fn(data), version=0)
+            merged = wire.merge_stats(traced.stats)
+    finally:
+        jax.profiler.stop_trace()
+    np.testing.assert_array_equal(plain.stats.num, traced.stats.num)
+    np.testing.assert_array_equal(plain.stats.den, traced.stats.den)
+    for a, b in zip(plain.payloads, traced.payloads):
+        np.testing.assert_array_equal(np.asarray(a.payload),
+                                      np.asarray(b.payload))
+
+    spans = _host_spans(tmp_path)
+    window = [s for s in spans if s[0] == "bench/window"]
+    assert len(window) == 1
+    program = [s for s in spans if s[0].startswith(obs.SPAN_PREFIX)]
+    expected = {"octopus/cohort", "octopus/server/merge"} | {
+        f"octopus/cohort/{c}" for c in COHORT_CHILDREN}
+    assert {s[0] for s in program} == expected    # nothing else octopus/
+    cohorts = [s for s in program if s[0] == "octopus/cohort"]
+    assert [c[3] for c in cohorts] == [
+        {"cohort": i, "clients": int(c.size), "version": 0}
+        for i, c in enumerate(plan.cohorts)]
+    for _, lo, hi, _ in cohorts:
+        assert window[0][1] <= lo and hi <= window[0][2]
+        inside = [s for s in program
+                  if s[0].startswith("octopus/cohort/") and lo <= s[1]
+                  and s[2] <= hi]
+        assert [s[0] for s in inside] == [f"octopus/cohort/{c}"
+                                          for c in COHORT_CHILDREN]
+        assert all(a[2] <= b[1] for a, b in zip(inside, inside[1:]))
+    merges = [s for s in program if s[0] == "octopus/server/merge"]
+    assert len(merges) == 1 and merges[0][3] == {"version": 0}
+    assert merged == 1 and merges[0][1] >= cohorts[-1][2]
+
+
+@pytest.mark.parametrize("bad", [np.arange(4), [1, 2], (1, 2), {"y": 1},
+                                 b"words"])
+def test_span_refuses_non_scalar_args(bad, tmp_path):
+    with pytest.raises(ValueError, match="scalar-only"):
+        obs.span("cohort", leak=bad)
+    with obs.recording(tmp_path / "t.jsonl") as rec:
+        with pytest.raises(ValueError, match="scalar-only"):
+            rec.span("decode", leak=bad)
+        with pytest.raises(ValueError, match="scalar-only"):
+            with obs.span("cohort", event="encode") as s:
+                s.add(leak=bad)
+    assert obs_report.load_events(str(tmp_path / "t.jsonl")) == []
+
+
+def test_span_writes_an_event_only_when_asked_and_recording(tmp_path):
+    """No recorder: a span writes nothing, ``event`` or not. With one
+    installed, only a span given ``event`` writes, once, with its args,
+    the fields added inside the block and the block's ``dur_ms``."""
+    with obs.span("cohort", event="encode", cohort=0) as s:
+        assert not s.recording
+        s.add(nbytes=8)
+    path = tmp_path / "t.jsonl"
+    with obs.recording(path) as rec:
+        with obs.span("cohort/pull") as s:
+            assert not s.recording
+        with obs.span("cohort", event="encode", cohort=2, clients=5) as s:
+            assert s.recording
+            s.add(nbytes=8)
+        with rec.span("decode", version=3):
+            pass
+    events = obs_report.load_events(str(path))
+    assert [e["kind"] for e in events] == ["encode", "decode"]
+    assert {k: events[0][k] for k in ("cohort", "clients", "nbytes")} == \
+        {"cohort": 2, "clients": 5, "nbytes": 8}
+    assert events[0]["dur_ms"] >= 0.0 and events[1]["version"] == 3
+    # one span implementation: the recorder's span is obs.span's type
+    assert type(rec.span("decode")) is type(obs.span("cohort"))
