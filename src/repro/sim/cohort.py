@@ -185,8 +185,9 @@ class CohortEngine:
         encode events (the computation never reads it).
 
         Each cohort runs inside the span ``octopus/cohort`` and its four
-        children in order: ``cohort/deploy`` (fresh clients, labels,
-        data), ``cohort/dispatch`` (enqueue the jitted round),
+        children in order: ``cohort/deploy`` (fresh clients in one
+        compiled dispatch, sharded over 'data' on a mesh; labels, data),
+        ``cohort/dispatch`` (enqueue the jitted round),
         ``cohort/pull`` (codebooks and EMA counts to the host: the only
         wait on the device) and ``cohort/fold`` (into the accumulator).
         """

@@ -21,10 +21,15 @@ population to advance per device call, so this engine:
     bytes are MEASURED from the buffers that would actually cross the
     network, per-client padding included (§2.8).
 
+Step 2's deployment of a population is ONE compiled dispatch too
+(``SimEngine.init_clients``): every leaf of the fresh ``ClientState`` is
+broadcast to ``(n_clients, ...)`` in one program, and on a mesh lands
+already sharded over 'data', where the round reads it.
+
 Typical use::
 
     eng = SimEngine(cfg, lr=1e-4, gamma=0.99)
-    clients = eng.init_clients(server, n_clients=256)
+    clients = eng.init_clients(server, n_clients=256)   # one dispatch
     clients, packed = eng.round(clients, data)     # data: (C, B, ...)
     server = eng.merge_into_server(server, clients)   # Step 5 tail
 """
@@ -53,7 +58,10 @@ def __getattr__(name):
 def replicate_clients(server: OC.ServerState, n_clients: int
                       ) -> OC.ClientState:
     """Step 2 deployment for a population: one ClientState pytree whose
-    leaves carry a leading (n_clients, ...) axis."""
+    leaves carry a leading (n_clients, ...) axis.
+
+    The plain definition, one op per leaf when called eagerly;
+    ``SimEngine.init_clients`` runs it as one compiled program."""
     client = OC.client_init(server)
     return jax.tree.map(
         lambda x: jnp.broadcast_to(x[None], (n_clients,) + x.shape), client)
@@ -127,11 +135,22 @@ class SimEngine:
                                      step=clients.step)
             return clients, payload
 
+        def _deploy(params, n_clients):
+            # the deploy reads only the server's params, never its AdamW
+            # state, so only they cross into the program
+            return replicate_clients(
+                OC.ServerState(params=params, opt=None, step=None),
+                n_clients)
+
         round_fn = _round
+        deploy_kw = {}
         if mesh is not None:
             from jax.sharding import Mesh, NamedSharding
             from jax.sharding import PartitionSpec as P
             spec = P("data")
+            # fresh clients land sharded over 'data', as the round reads
+            # them: no reshard before each cohort's round
+            deploy_kw["out_shardings"] = NamedSharding(mesh, spec)
             step = jax.shard_map(step, mesh=mesh, in_specs=(spec, spec),
                                  out_specs=(spec, spec), check_vma=False)
             # the WHOLE round — encode, fused dispatch, EMA — runs inside
@@ -146,13 +165,18 @@ class SimEngine:
         self._step = step
         self._step_jit = jax.jit(step)
         self._round = jax.jit(round_fn)
+        self._deploy = jax.jit(_deploy, static_argnums=1, **deploy_kw)
         self._shape_cache = {}
 
     # ------------------------------------------------------------- rounds
 
     def init_clients(self, server: OC.ServerState, n_clients: int
                      ) -> OC.ClientState:
-        return replicate_clients(server, n_clients)
+        """Step 2: ``n_clients`` fresh clients deployed from ``server``,
+        bit-identical to ``replicate_clients`` but in ONE compiled
+        dispatch (one program per ``n_clients``); on a mesh every leaf
+        comes out sharded over 'data'."""
+        return self._deploy(server.params, int(n_clients))
 
     def round(self, clients: OC.ClientState, data, *, version: int = 0,
               labels=None) -> Tuple[OC.ClientState, CodePayload]:

@@ -5,6 +5,13 @@ The two contracts that let the engine replace the Python client loop:
     ``octopus.client_round`` calls (allclose; indices exactly equal),
   * pack -> unpack of code indices is bit-exact, with Pallas/jnp parity.
 """
+import json
+import os
+import subprocess
+import sys
+import textwrap
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,7 +22,9 @@ from repro.core.dvqae import DVQAEConfig
 from repro.kernels import ops, ref
 from repro.kernels.pack_bits import code_bits, packing_dims
 from repro.server import CodeStore
-from repro.sim import SimEngine, stack_clients
+from repro.sim import SimEngine, replicate_clients, stack_clients
+
+ROOT = Path(__file__).resolve().parents[1]
 
 
 @pytest.fixture(scope="module")
@@ -137,6 +146,133 @@ def test_engine_merge_matches_sequence_merge(tiny_cfg, server, key):
     np.testing.assert_allclose(np.asarray(merged.params["codebook"]),
                                np.asarray(ref_merged.params["codebook"]),
                                rtol=1e-6)
+
+
+# ------------------------------------------------------------------ deploy
+
+@pytest.mark.parametrize("n_clients", [2, 16, 17])
+def test_init_clients_is_eager_replicate_bit_for_bit(tiny_cfg, server,
+                                                     n_clients):
+    """The one-dispatch deploy gives every leaf of the eager
+    ``replicate_clients``: same values, dtypes and shapes."""
+    engine = SimEngine(tiny_cfg)
+    got = engine.init_clients(server, n_clients)
+    want = replicate_clients(server, n_clients)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.shape == w.shape and g.shape[0] == n_clients
+        assert g.dtype == w.dtype
+        assert g.weak_type == w.weak_type
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_init_clients_compiles_once_per_size(tiny_cfg, server):
+    """One program per ``n_clients``: a second deploy of the same size,
+    from a server with new values, compiles nothing."""
+    engine = SimEngine(tiny_cfg)
+    engine.init_clients(server, 4)
+    size = engine._deploy._cache_size()
+    moved = server._replace(params={
+        **server.params, "codebook": server.params["codebook"] + 1.0})
+    again = engine.init_clients(moved, 4)
+    assert engine._deploy._cache_size() == size
+    np.testing.assert_array_equal(
+        np.asarray(again.params["codebook"][3]),
+        np.asarray(moved.params["codebook"]))
+    engine.init_clients(server, 6)
+    assert engine._deploy._cache_size() == size + 1
+
+
+def test_cohort_round_deploys_once_per_cohort_inside_its_span(
+        tiny_cfg, server, key, monkeypatch):
+    """``CohortEngine.round`` still opens exactly one
+    ``octopus/cohort/deploy`` per cohort, and the engine's deploy runs
+    inside it."""
+    from repro.obs import recorder
+    from repro.sim import CohortEngine, CohortPlan
+    log = []
+
+    class Annotation:
+        def __init__(self, name, **args):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+
+    monkeypatch.setattr(recorder, "TraceAnnotation", Annotation)
+    eng = CohortEngine(tiny_cfg, gamma=0.9)
+    deploy = eng.engine.init_clients
+
+    def init_clients(*a, **kw):
+        log.append(("call", "init_clients"))
+        return deploy(*a, **kw)
+    monkeypatch.setattr(eng.engine, "init_clients", init_clients)
+    data = jax.random.normal(key, (7, 2, 8, 8, 3))
+    plan = CohortPlan.build(np.arange(7), 3)       # cohorts of 3 and 4
+    eng.round(server, plan, lambda ids: data[ids])
+    deploys = [i for i, e in enumerate(log)
+               if e == ("enter", "octopus/cohort/deploy")]
+    assert len(deploys) == plan.n_cohorts == 2
+    for i in deploys:
+        assert log[i + 1] == ("call", "init_clients")
+    assert log.count(("call", "init_clients")) == plan.n_cohorts
+    assert log.count(("exit", "octopus/cohort/deploy")) == plan.n_cohorts
+
+
+MESH_CHILD = textwrap.dedent("""
+    import json, sys
+    sys.path[:0] = [{src!r}]
+    import jax
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.core import octopus as OC
+    from repro.core.dvqae import DVQAEConfig
+    from repro.launch.mesh import make_host_mesh
+    from repro.sim import SimEngine
+    cfg = DVQAEConfig(kind="image", in_channels=3, hidden=8, latent_dim=8,
+                      codebook_size=16, n_res_blocks=1)
+    server = OC.server_init(jax.random.PRNGKey(0), cfg)
+    data = jax.random.normal(jax.random.PRNGKey(1), (8, 2, 8, 8, 3))
+    mesh = make_host_mesh()
+    sharded = SimEngine(cfg, gamma=0.9, mesh=mesh)
+    clients = sharded.init_clients(server, 8)
+    want = NamedSharding(mesh, P("data"))
+    leaves = jax.tree.leaves(clients)
+    sharded_ok = all(
+        getattr(x.sharding, "spec", None) == P("data")
+        and x.sharding.is_equivalent_to(want, x.ndim)
+        and len({{s.device for s in x.addressable_shards}}) == 4
+        and all(s.data.shape[0] == 2 for s in x.addressable_shards)
+        for x in leaves)
+    plain = SimEngine(cfg, gamma=0.9)
+    _, p1 = plain.round(plain.init_clients(server, 8), data)
+    _, p2 = sharded.round(clients, data)
+    print(json.dumps({{
+        "devices": len(jax.devices()), "leaves": len(leaves),
+        "sharded": sharded_ok,
+        "codes_equal": bool(np.array_equal(np.asarray(p1.unpack()),
+                                           np.asarray(p2.unpack())))}}))
+""")
+
+
+def test_init_clients_lands_sharded_on_a_mesh():
+    """On four (virtual CPU) devices, in a child process that sets the
+    device count before JAX starts: every deployed leaf comes out
+    sharded ``P("data")``, two clients per device, and the sharded round
+    on them sends the plain engine's codes."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    code = MESH_CHILD.format(src=str(ROOT / "src"))
+    p = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    out = json.loads(p.stdout.strip().splitlines()[-1])
+    assert out["devices"] == 4 and out["leaves"] > 0
+    assert out["sharded"] is True
+    assert out["codes_equal"] is True
 
 
 # ------------------------------------------------------------------ ingest
