@@ -131,11 +131,14 @@ class _Span:
         return self._rec is not None
 
     def add(self, **fields) -> None:
-        """Event fields known only inside the block (a payload's metadata
-        after its dispatch). They go to the recorder's event; the
-        annotation's args were fixed at entry."""
+        """Fields known only inside the block (a payload's metadata after
+        its dispatch): args of the profiler's span, and fields of the
+        recorder's event."""
         if self._rec is not None:
+            # taken first, so that a refused field refuses the event too
             self._fields.update(fields)
+        _refuse_non_scalars("span", fields)
+        self._ann.set_metadata(**fields)
 
     def __enter__(self) -> "_Span":
         self._ann.__enter__()

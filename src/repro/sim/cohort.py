@@ -184,9 +184,11 @@ class CohortEngine:
         ``round_idx`` only labels the flight recorder's per-cohort
         encode events (the computation never reads it).
 
-        Each cohort runs inside the span ``octopus/cohort`` and its four
-        children in order: ``cohort/deploy`` (fresh clients in one
-        compiled dispatch, sharded over 'data' on a mesh; labels, data),
+        Each cohort runs inside the span ``octopus/cohort`` (args
+        ``cohort``, ``clients``, ``version`` and ``positions``, the latent
+        positions of one client record) and its four children in order:
+        ``cohort/deploy`` (fresh clients in one compiled dispatch,
+        sharded over 'data' on a mesh; labels, data),
         ``cohort/dispatch`` (enqueue the jitted round),
         ``cohort/pull`` (codebooks and EMA counts to the host: the only
         wait on the device) and ``cohort/fold`` (into the accumulator).
@@ -206,6 +208,8 @@ class CohortEngine:
                 with _obs.span("cohort/dispatch"):
                     clients, payload = self.engine.round(
                         clients, data, version=version, labels=labels)
+                # index shape (C, B, T[, n_c]): samples x positions each
+                span.add(positions=int(np.prod(payload.shape[1:3])))
                 # the one place the host waits on the device
                 with _obs.span("cohort/pull"):
                     codebooks = np.asarray(clients.params["codebook"])

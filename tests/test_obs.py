@@ -413,8 +413,9 @@ def test_cohort_round_spans_on_the_profiler_clock(tiny_cfg, server, data,
         f"octopus/cohort/{c}" for c in COHORT_CHILDREN}
     assert {s[0] for s in program} == expected    # nothing else octopus/
     cohorts = [s for s in program if s[0] == "octopus/cohort"]
+    # 2 images of 8x8 per client: 2 x (8/4)^2 latent positions
     assert [c[3] for c in cohorts] == [
-        {"cohort": i, "clients": int(c.size), "version": 0}
+        {"cohort": i, "clients": int(c.size), "version": 0, "positions": 8}
         for i, c in enumerate(plan.cohorts)]
     for _, lo, hi, _ in cohorts:
         assert window[0][1] <= lo and hi <= window[0][2]
@@ -427,6 +428,32 @@ def test_cohort_round_spans_on_the_profiler_clock(tiny_cfg, server, data,
     merges = [s for s in program if s[0] == "octopus/server/merge"]
     assert len(merges) == 1 and merges[0][3] == {"version": 0}
     assert merged == 1 and merges[0][1] >= cohorts[-1][2]
+
+
+@pytest.mark.parametrize("kind,sample,groups,per_sample", [
+    ("image", (8, 8, 3), 1, (8 // 4) * (8 // 4)),
+    ("speech", (64, 1), 4, 64 // 4)])
+def test_cohort_span_counts_latent_positions(kind, sample, groups,
+                                             per_sample, tmp_path):
+    """``positions`` of each ``octopus/cohort`` span is the latent
+    positions of one client record, from the data's shape: images x
+    H/4 x W/4 for an image cohort, clips x T/4 for a speech cohort (here
+    with GSVQ, whose codes carry a slice axis too)."""
+    cfg = DVQAEConfig(kind=kind, in_channels=sample[-1], hidden=8,
+                      latent_dim=8, codebook_size=16, n_res_blocks=1,
+                      n_groups=groups, n_slices=2 if groups > 1 else 1)
+    srv = OC.server_init(jax.random.PRNGKey(0), cfg)
+    per_client = 3
+    x = jax.random.normal(jax.random.PRNGKey(1), (4, per_client) + sample)
+    engine = CohortEngine(cfg, gamma=0.9, n_local_steps=0)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        engine.round(srv, CohortPlan.build(np.arange(4), 2), _data_fn(x))
+    finally:
+        jax.profiler.stop_trace()
+    cohorts = [s for s in _host_spans(tmp_path) if s[0] == "octopus/cohort"]
+    assert [c[3]["positions"] for c in cohorts] == \
+        [per_client * per_sample] * 2
 
 
 @pytest.mark.parametrize("bad", [np.arange(4), [1, 2], (1, 2), {"y": 1},

@@ -202,6 +202,9 @@ def test_cohort_round_deploys_once_per_cohort_inside_its_span(
         def __exit__(self, *exc):
             log.append(("exit", self.name))
 
+        def set_metadata(self, **args):
+            pass
+
     monkeypatch.setattr(recorder, "TraceAnnotation", Annotation)
     eng = CohortEngine(tiny_cfg, gamma=0.9)
     deploy = eng.engine.init_clients
