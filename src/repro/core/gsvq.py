@@ -19,6 +19,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 
 class GSVQOut(NamedTuple):
@@ -28,32 +29,26 @@ class GSVQOut(NamedTuple):
     commit_loss: jax.Array
 
 
-def _group_distances(z, codebook, n_groups: int):
-    """Mean per-group L2 distance (Eq. 2).
+def _distances(z, codebook):
+    """Euclidean distance of every row to every atom: the (N, K) matrix
+    that Eq. 2 averages and Eq. 3 weights.
 
-    z: (N, m); codebook: (K, m) -> (N, G).
+    z: (N, m); codebook: (K, m) -> (N, K). The cross term runs at
+    ``HIGHEST``, as the encode kernel's match does. Rounding can take the
+    expanded square below zero; ``where`` (not ``maximum``) clamps it, so
+    the backward pass carries no tie mask.
     """
-    K = codebook.shape[0]
-    ng = K // n_groups
-    # full pairwise distance then mean-pool over groups; the Pallas kernel
-    # streams this without materialising (N, K) when K is large.
     z2 = jnp.sum(jnp.square(z), axis=-1, keepdims=True)
     e2 = jnp.sum(jnp.square(codebook), axis=-1)[None, :]
-    d2 = jnp.maximum(z2 - 2.0 * (z @ codebook.T) + e2, 0.0)      # (N, K)
-    d = jnp.sqrt(d2 + 1e-12)
-    return jnp.mean(d.reshape(-1, n_groups, ng), axis=-1)        # (N, G)
+    cross = jnp.matmul(z, codebook.T, precision=lax.Precision.HIGHEST)
+    d2 = z2 - 2.0 * cross + e2
+    return jnp.sqrt(jnp.where(d2 > 0.0, d2, 0.0) + 1e-12)
 
 
-def _group_weighted_average(z, group_atoms):
-    """Inverse-distance-weighted atom average (Eq. 3).
-
-    z: (N, m); group_atoms: (N, N_g, m) atoms of each row's matched group.
-    """
-    d = jnp.sqrt(jnp.sum(jnp.square(z[:, None, :] - group_atoms), axis=-1)
-                 + 1e-12)                                        # (N, N_g)
-    w = 1.0 / (d + 1e-8)
-    w = w / jnp.sum(w, axis=-1, keepdims=True)
-    return jnp.einsum("ng,ngm->nm", w, group_atoms)
+def _match_groups(d, n_groups: int):
+    """Eq. 2: the group of least mean atom distance. d: (N, K) -> (N,)."""
+    gd = jnp.mean(d.reshape(d.shape[0], n_groups, -1), axis=-1)  # (N, G)
+    return jnp.argmin(lax.stop_gradient(gd), axis=-1).astype(jnp.int32)
 
 
 def gsvq_quantize(z_e, codebook, *, n_groups: int = 1, n_slices: int = 1) -> GSVQOut:
@@ -66,17 +61,19 @@ def gsvq_quantize(z_e, codebook, *, n_groups: int = 1, n_slices: int = 1) -> GSV
     assert M % n_slices == 0, (M, n_slices)
     assert K % n_groups == 0, (K, n_groups)
     m = M // n_slices
-    ng = K // n_groups
+    group_of = jnp.arange(K, dtype=jnp.int32) // (K // n_groups)  # (K,)
 
     zf = z_e.reshape(-1, n_slices, m)                            # (N, n_c, m)
     cb = codebook.reshape(K, n_slices, m).transpose(1, 0, 2)     # (n_c, K, m)
 
     def per_slice(z_s, cb_s):
-        gd = _group_distances(z_s, cb_s, n_groups)               # (N, G)
-        gidx = jnp.argmin(gd, axis=-1).astype(jnp.int32)         # (N,)
-        groups = cb_s.reshape(n_groups, ng, m)
-        atoms = groups[gidx]                                     # (N, N_g, m)
-        zq = _group_weighted_average(z_s, atoms)
+        d = _distances(z_s, cb_s)                                # (N, K)
+        gidx = _match_groups(d, n_groups)                        # (N,)
+        # Eq. 3 from the same matrix: inverse-distance weights, exactly
+        # zero outside the matched group, normalised after the matmul
+        w = jnp.where(group_of == gidx[:, None], 1.0 / (d + 1e-8), 0.0)
+        zq = (jnp.matmul(w, cb_s, precision=lax.Precision.HIGHEST)
+              / jnp.sum(w, axis=-1, keepdims=True))
         return zq, gidx
 
     zq, gidx = jax.vmap(per_slice, in_axes=(1, 0), out_axes=(1, 1))(zf, cb)
@@ -103,8 +100,7 @@ def gsvq_indices(z_e, codebook, *, n_groups: int = 1, n_slices: int = 1):
     cb = codebook.reshape(K, n_slices, m).transpose(1, 0, 2)
 
     def per_slice(z_s, cb_s):
-        gd = _group_distances(z_s, cb_s, n_groups)
-        return jnp.argmin(gd, axis=-1).astype(jnp.int32)
+        return _match_groups(_distances(z_s, cb_s), n_groups)
 
     gidx = jax.vmap(per_slice, in_axes=(1, 0), out_axes=1)(zf, cb)
     return gidx.reshape(*lead, n_slices)

@@ -83,3 +83,86 @@ def test_shapes_roundtrip(key, n_groups, n_slices):
                                        n_slices=n_slices)
     assert rec.shape == z.shape
     assert bool(jnp.all(jnp.isfinite(rec)))
+
+
+def _gather_form(z_e, codebook, n_groups, n_slices):
+    """Eq. 2-3 in the gathered-atom form: gather each row's matched-group
+    atoms as an (N, N_g, m) tensor and recompute their distances. The
+    reference for ``gsvq_quantize``'s matmul form."""
+    *lead, M = z_e.shape
+    K = codebook.shape[0]
+    m, ng = M // n_slices, K // n_groups
+    zf = z_e.reshape(-1, n_slices, m)
+    cb = codebook.reshape(K, n_slices, m).transpose(1, 0, 2)
+
+    def per_slice(z_s, cb_s):
+        d = jnp.sqrt(jnp.sum(jnp.square(z_s[:, None] - cb_s[None]), -1)
+                     + 1e-12)                                    # (N, K)
+        gidx = jnp.argmin(jnp.mean(d.reshape(-1, n_groups, ng), -1), -1)
+        atoms = cb_s.reshape(n_groups, ng, m)[gidx]              # (N, N_g, m)
+        da = jnp.sqrt(jnp.sum(jnp.square(z_s[:, None] - atoms), -1)
+                      + 1e-12)
+        w = 1.0 / (da + 1e-8)
+        w = w / jnp.sum(w, -1, keepdims=True)
+        return jnp.einsum("ng,ngm->nm", w, atoms), gidx.astype(jnp.int32)
+
+    zq, gidx = jax.vmap(per_slice, in_axes=(1, 0), out_axes=(1, 1))(zf, cb)
+    return zq.reshape(*lead, M), gidx.reshape(*lead, n_slices)
+
+
+def _gather_terms(z, cb, n_groups, n_slices):
+    """codebook_loss + 0.25 * commit_loss of the gather form."""
+    zq = _gather_form(z, cb, n_groups, n_slices)[0]
+    sg = jax.lax.stop_gradient
+    return (jnp.mean(jnp.square(sg(z) - zq))
+            + 0.25 * jnp.mean(jnp.square(z - sg(zq))))
+
+
+def _matmul_terms(z, cb, n_groups, n_slices):
+    q = gsvq.gsvq_quantize(z, cb, n_groups=n_groups, n_slices=n_slices)
+    return q.codebook_loss + 0.25 * q.commit_loss
+
+
+@pytest.mark.parametrize("n_groups,n_slices,K,M,exact_row", [
+    (2, 1, 32, 16, False), (4, 2, 32, 16, False), (8, 4, 32, 16, False),
+    (8, 4, 256, 64, False), (8, 4, 256, 64, True)])
+def test_matmul_form_matches_gather_form(key, n_groups, n_slices, K, M,
+                                         exact_row):
+    """Eq. 3 taken from the Eq. 2 distance matrix by masked matmuls equals
+    the gathered-atom form: same indices, quantized latents within 1e-5,
+    and the gradient of the Eq. 6 quantizer terms w.r.t. z within 1e-5
+    relative. With a row equal to an atom, everything stays finite and
+    Eq. 3 puts (nearly) all weight on that atom."""
+    k1, k2 = jax.random.split(key)
+    z = jax.random.normal(k1, (4, 32, M))
+    cb = jax.random.normal(k2, (K, M))
+    ng = K // n_groups
+    lo = 37 // ng * ng                   # first atom of atom 37's group
+    if exact_row:
+        # atom 37's group gathered round it, so Eq. 2 matches that group
+        # in every slice, and one row of z set to the atom itself
+        near = cb[37] + 0.5 * jax.random.normal(k1, (ng, M))
+        cb = cb.at[lo:lo + ng].set(near.at[37 - lo].set(cb[37]))
+        z = z.at[1, 5].set(cb[37])
+    q = gsvq.gsvq_quantize(z, cb, n_groups=n_groups, n_slices=n_slices)
+    grad = np.asarray(jax.grad(_matmul_terms)(z, cb, n_groups, n_slices))
+    assert np.all(np.isfinite(np.asarray(q.quantized)))
+    assert np.all(np.isfinite(grad))
+    want_zq, want_idx = _gather_form(z, cb, n_groups, n_slices)
+    np.testing.assert_array_equal(np.asarray(q.indices), np.asarray(want_idx))
+    if exact_row:
+        m = M // n_slices
+        np.testing.assert_array_equal(np.asarray(q.indices[1, 5]),
+                                      np.full(n_slices, 37 // ng))
+        atom = np.asarray(cb[37]).reshape(n_slices, m)
+        got = np.asarray(q.quantized[1, 5]).reshape(n_slices, m)
+        others = np.asarray(cb[lo:lo + ng]).reshape(ng, n_slices, m)
+        nearest = np.sort(np.linalg.norm(others - atom, axis=-1), 0)[1]
+        assert np.all(np.linalg.norm(got - atom, axis=-1) < 0.05 * nearest)
+        return
+    np.testing.assert_allclose(np.asarray(q.quantized), np.asarray(want_zq),
+                               rtol=0, atol=1e-5)
+    want_grad = np.asarray(jax.grad(_gather_terms)(z, cb, n_groups,
+                                                   n_slices))
+    assert (np.linalg.norm(grad - want_grad)
+            <= 1e-5 * np.linalg.norm(want_grad))
