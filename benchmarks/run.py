@@ -17,7 +17,7 @@ Paper targets:
   kernels  Pallas kernel microbenchmarks vs jnp reference
   gsvq     GSVQ (groups x slices) accuracy vs bits-per-position
   sim      batched multi-client engine (repro.sim) throughput + uplink
-  server   async code-server runtime (repro.server): rounds/sec, decode
+  server   code-server runtime (repro.server): rounds/sec, decode
            amortization, bytes-per-accuracy across traffic scenarios
   decode   fused packed-code->feature decode (kernels/decode_codes.py)
            vs the unpack-then-dequantize baseline
@@ -609,15 +609,16 @@ def bench_sim(key):
 # ---------------------------------------------------------------- server
 
 def bench_server(key):
-    """Async code-server runtime across STANDARD_SCENARIOS: rounds/sec,
-    measured uplink bytes, multi-task accuracy from one decode, and the
-    decode amortization factor (schema in the module docstring)."""
+    """Scheduler-driven traffic (``CohortEngine.run_traffic`` into an
+    ``OctopusServer``) across STANDARD_SCENARIOS: rounds/sec, measured
+    uplink bytes, multi-task accuracy from one decode, and the decode
+    amortization factor (schema in the module docstring)."""
     from repro.core import octopus as OC
     from repro.core.dvqae import DVQAEConfig
     from repro.data import make_images, partition_stacked
     from repro.launch.octopus_server import run_scenario
     from repro.server import STANDARD_SCENARIOS, MultiTaskTrainer, TaskSpec
-    from repro.sim import SimEngine
+    from repro.sim import CohortEngine
 
     n_slots = 8 if C.QUICK else 16
     local_b, rounds = 8, (4 if C.QUICK else 8)
@@ -628,28 +629,30 @@ def bench_server(key):
     server, _ = OC.server_pretrain(key, OC.server_init(key, cfg), cfg,
                                    data.x, steps=20 if C.QUICK else 60)
     stacked = partition_stacked(data, n_slots, regime="skewed", skew=0.2)
-    engine = SimEngine(cfg, lr=1e-4, gamma=0.95)
+    engine = CohortEngine(cfg, lr=1e-4, gamma=0.95)
     tasks = [TaskSpec("content", int(stacked.content.max()) + 1),
              TaskSpec("style", int(stacked.style.max()) + 1)]
 
-    last_srv = None
+    last = None
     for i, (name, sc) in enumerate(STANDARD_SCENARIOS.items()):
-        srv, acc, rps = run_scenario(
+        run = run_scenario(
             name, sc, engine=engine, server=server, stacked=stacked,
             slots=n_slots, rounds=rounds, local_batch=local_b,
             probe_steps=C.PROBE_STEPS, key=key, index=i, verbose=False)
-        _emit("server", f"{name}_rounds_per_sec", f"{rps:.2f}")
-        _emit("server", f"{name}_participants", srv.scheduler.k)
-        _emit("server", f"{name}_bytes_delivered", srv.bytes_delivered)
-        _emit("server", f"{name}_bytes_sent", srv.bytes_sent,
+        delivered = run.queue.bytes_delivered
+        _emit("server", f"{name}_rounds_per_sec",
+              f"{run.rounds_per_sec:.2f}")
+        _emit("server", f"{name}_participants", run.participants)
+        _emit("server", f"{name}_bytes_delivered", delivered)
+        _emit("server", f"{name}_bytes_sent", run.queue.bytes_sent,
               extra="incl. dropped / in-flight")
-        _emit("server", f"{name}_store_records", len(srv.store),
-              extra="v" + "+".join(map(str, srv.store.versions)))
-        for t, a in acc.items():
+        _emit("server", f"{name}_store_records", len(run.wire.store),
+              extra="v" + "+".join(map(str, run.wire.store.versions)))
+        for t, a in run.acc.items():
             _emit("server", f"{name}_acc_{t}", f"{a:.4f}")
         _emit("server", f"{name}_bytes_per_point",
-              f"{srv.bytes_delivered / max(acc['content'], 1e-3):.0f}")
-        last_srv = srv
+              f"{delivered / max(run.acc['content'], 1e-3):.0f}")
+        last = run
 
     # decode amortization, measured end-to-end: training every head from
     # ONE shared decode vs a per-task pipeline that re-decodes the store
@@ -657,19 +660,19 @@ def bench_server(key):
     # Every trainer's jitted step is warmed first so the ratio measures
     # decode + train work, not compile-count asymmetry.
     steps = max(C.PROBE_STEPS // 4, 10)
-    feats, labels = last_srv.dataset()
+    feats, labels = last.wire.features()
     in_dim = int(feats[0].size)
     shared = MultiTaskTrainer(key, tasks, in_dim)
     singles = [MultiTaskTrainer(key, [t], in_dim) for t in tasks]
     for tr in [shared] + singles:
         tr.fit(key, feats, labels, steps=1, batch=64)      # compile warmup
     t0 = time.time()
-    feats, labels = last_srv.dataset()
+    feats, labels = last.wire.features()
     shared.fit(key, feats, labels, steps=steps, batch=64)
     t_shared = max(time.time() - t0, 1e-9)
     t0 = time.time()
     for tr in singles:
-        feats, labels = last_srv.dataset()                 # per-task decode
+        feats, labels = last.wire.features()              # per-task decode
         tr.fit(key, feats, labels, steps=steps, batch=64)
     t_per_task = time.time() - t0
     _emit("server", "decode_amortization", f"{t_per_task / t_shared:.2f}",

@@ -231,8 +231,8 @@ def server_merge_codebooks(server: ServerState,
     sim engine carries.
 
     ``staleness`` (optional, (M_clients,) int): how many codebook versions
-    behind the global dictionary each client's sync is — the async server
-    runtime (repro.server) discounts stale contributions by
+    behind the global dictionary each client's sync is —
+    ``repro.server.CodebookRegistry.merge`` discounts stale contributions by
     ``staleness_decay ** staleness`` on top of the count weights, so a
     client that slept through two merges pulls the dictionary less than
     one that synced last round.
@@ -309,11 +309,11 @@ def client_round(client: ClientState, cfg: DVQAEConfig, batch, *,
     times — forward for the indices, then forward AND encode again
     inside the refresh — for the same latents.)
 
-    Returns (new_client, int32 indices); packing the indices across the
-    whole population at once is the engine's job (one big packed buffer
-    beats per-client slivers). ``repro.wire.OctopusClient.round`` is the
-    session entry whose uplink never materializes the index tensor at
-    all and ships a ``CodePayload``.
+    Returns (new_client, int32 indices). This unfused jnp path is the
+    tests' reference for the program paths — ``SimEngine`` and
+    ``repro.wire.OctopusClient.round``, both through
+    :func:`client_uplink` — which never materialize the index tensor
+    and ship a ``CodePayload``.
     """
     client, z = client_finetune_encode(client, cfg, batch, lr=lr,
                                        n_local_steps=n_local_steps)
@@ -321,6 +321,29 @@ def client_round(client: ClientState, cfg: DVQAEConfig, batch, *,
     client = client_codebook_refresh(client, cfg, batch, gamma=gamma,
                                      stats=refresh_stats(cfg, z, idx))
     return client, idx
+
+
+def client_uplink(clients: ClientState, cfg: DVQAEConfig, z, *,
+                  gamma: float = 0.99, refresh: bool = True
+                  ) -> Tuple[ClientState, jax.Array]:
+    """The fused Steps 3-5 tail of C >= 1 client records.
+
+    ``clients`` carries a leading (C, ...) axis; ``z`` is (C, P, M),
+    each record's quantizer-space latents. ONE ``ops.encode_codes``
+    dispatch matches every record against its own (K, M) codebook,
+    bit-packs its codes into its own uint32 record stream and reduces
+    the Eq. 7-8 statistics, from which the Step 5 EMA refresh follows
+    (skipped with ``refresh=False``). Neither the (N, K) distance matrix
+    nor the int32 index tensor exists. Returns (clients, (C, W) words).
+    """
+    from repro.kernels.ops import encode_codes
+    words, counts, sums = encode_codes(
+        z, clients.params["codebook"], bits=transmit_bits(cfg),
+        n_groups=cfg.n_groups, n_slices=cfg.n_slices)
+    if refresh:
+        clients = client_codebook_refresh(clients, cfg, None, gamma=gamma,
+                                          stats=(counts, sums))
+    return clients, words
 
 
 # --------------------------------------------------------------- Step 6

@@ -1,10 +1,13 @@
-"""Async code-server launch entry: scheduler scenarios over the runtime.
+"""Code-server launch entry: scheduler scenarios over the cohort engine.
 
 Drives the repro.server subsystem end-to-end — pretrain a global DVQ-AE,
 replay one (or every) STANDARD_SCENARIOS traffic profile through
-``AsyncCodeServer``, then train the multi-task heads from one decode of
-the versioned CodeStore. Prints per-scenario rounds/sec, measured uplink
-bytes, store/version state and task accuracies.
+``CohortEngine.run_traffic`` into an ``OctopusServer`` (fresh deploys
+each round, cohorts per delivery fate on one shared ``UplinkQueue``,
+Step 5 merges every ``merge_every`` rounds), then train the multi-task
+heads from one decode of the versioned CodeStore. Prints per-scenario
+rounds/sec, measured uplink bytes, store/version state and task
+accuracies.
 
     PYTHONPATH=src python -m repro.launch.octopus_server \
         [--scenario full|partial|churn|all] [--slots 8] [--rounds 8] \
@@ -15,55 +18,82 @@ bytes, store/version state and task accuracies.
 from __future__ import annotations
 
 import argparse
+import itertools
 import time
+from typing import Dict, NamedTuple
 
 import jax
+import numpy as np
 
 from repro.core import octopus as OC
 from repro.core.dvqae import DVQAEConfig
 from repro.data import make_images, partition_stacked, stacked_batches
-from repro.server import (STANDARD_SCENARIOS, AsyncCodeServer,
-                          MultiTaskTrainer, RoundScheduler, TaskSpec)
-from repro.sim import SimEngine
+from repro.server import (STANDARD_SCENARIOS, MultiTaskTrainer,
+                          RoundScheduler, TaskSpec, UplinkQueue)
+from repro.sim import CohortEngine
+from repro.wire import OctopusServer
+
+
+class ScenarioRun(NamedTuple):
+    """What one traffic scenario left behind."""
+    wire: OctopusServer        # registry + store; ``features()`` decodes
+    queue: UplinkQueue         # the measured §2.8 byte ledger
+    participants: int          # clients drawn per round
+    n_merges: int
+    acc: Dict[str, float]      # task -> head accuracy on the store
+    rounds_per_sec: float      # rounds 1.. (round 0 pays compilation)
 
 
 def run_scenario(name, scenario, *, engine, server, stacked, slots, rounds,
                  local_batch, probe_steps, key, index: int = 0,
-                 verbose: bool = True):
-    """Drive one traffic scenario through the runtime, then train the
-    two standard heads from one store decode. Shared by this CLI and
-    ``benchmarks.run::bench_server`` — returns (srv, acc, rounds_per_sec).
+                 verbose: bool = True) -> ScenarioRun:
+    """Drive one traffic scenario through ``engine.run_traffic`` (a
+    ``CohortEngine``; one cohort per delivery group), then train the two
+    standard heads from one store decode. Shared by this CLI and
+    ``benchmarks.run::bench_server``.
     """
     if rounds < 2:
         raise ValueError("need rounds >= 2: round 0 is the compile warmup, "
                          "rounds/sec is timed over the rest")
     sched = RoundScheduler(slots, scenario.sched,
                            key=jax.random.fold_in(key, index))
-    srv = AsyncCodeServer(engine, server, sched,
-                          merge_every=scenario.merge_every,
-                          staleness_decay=0.5)
-    t0 = time.time()
-    for r, b in zip(range(rounds),
-                    stacked_batches(stacked, local_batch, epochs=rounds)):
-        if r == 1:
-            t0 = time.time()                    # round 0 pays compilation
-        srv.run_round(b.x, labels={"content": b.content, "style": b.style})
-    rps = (rounds - 1) / max(time.time() - t0, 1e-9)
+    wire, queue = OctopusServer(server, engine.cfg), UplinkQueue()
+    batches = list(itertools.islice(
+        stacked_batches(stacked, local_batch, epochs=rounds), rounds))
+    started = {}
 
-    feats, labels = srv.dataset()
+    def data_fn(ids):
+        r = sched.round - 1                      # the round being drawn
+        started.setdefault(r, time.time())
+        return batches[r].x[np.asarray(ids)]
+
+    def labels_fn(ids):
+        b, ids = batches[sched.round - 1], np.asarray(ids)
+        return {"content": b.content[ids], "style": b.style[ids]}
+
+    history = engine.run_traffic(
+        wire, sched, data_fn, labels_fn=labels_fn, cohort_size=slots,
+        n_rounds=rounds, merge_every=scenario.merge_every, queue=queue)
+    rps = (rounds - 1) / max(time.time() - started[1], 1e-9)
+
+    feats, labels = wire.features()
     tasks = [TaskSpec("content", int(stacked.content.max()) + 1),
              TaskSpec("style", int(stacked.style.max()) + 1)]
     trainer = MultiTaskTrainer(key, tasks, int(feats[0].size))
     trainer.fit(key, feats, labels, steps=probe_steps, batch=64)
     acc = trainer.accuracy(feats, labels)
+    run = ScenarioRun(wire=wire, queue=queue, participants=sched.k,
+                      n_merges=sum(h.merged_version is not None
+                                   for h in history),
+                      acc=acc, rounds_per_sec=rps)
     if verbose:
-        print(f"[{name}] {rps:.2f} rounds/sec | bytes sent={srv.bytes_sent} "
-              f"delivered={srv.bytes_delivered} "
-              f"dropped={srv.bytes_dropped} | "
-              f"store {len(srv.store)} recs v{list(srv.store.versions)} "
-              f"({srv.n_merges} merges) | "
+        print(f"[{name}] {rps:.2f} rounds/sec | bytes sent="
+              f"{queue.bytes_sent} delivered={queue.bytes_delivered} "
+              f"dropped={queue.bytes_dropped} | "
+              f"store {len(wire.store)} recs v{list(wire.store.versions)} "
+              f"({run.n_merges} merges) | "
               + " ".join(f"{t}={a:.3f}" for t, a in acc.items()))
-    return srv, acc, rps
+    return run
 
 
 def main():
@@ -94,7 +124,7 @@ def main():
         print(f"pretrain recon loss: {float(out.recon_loss):.4f}")
 
     stacked = partition_stacked(data, args.slots, regime="skewed", skew=0.2)
-    engine = SimEngine(cfg, lr=1e-4, gamma=0.95)
+    engine = CohortEngine(cfg, lr=1e-4, gamma=0.95)
     names = sorted(STANDARD_SCENARIOS) if args.scenario == "all" \
         else [args.scenario]
     for i, name in enumerate(names):

@@ -15,8 +15,9 @@ scale).
   runtime    — ContinuousIngestService: clocked, admission-controlled
                ingest (backpressure verdicts, exactly-once dedup window,
                background bulk decode under a BulkDecodePolicy) with
-               journaled crash recovery (``recover``); AsyncCodeServer
-               remains the round-quantized shim over it
+               journaled crash recovery (``recover``); UplinkQueue: the
+               in-flight payloads and the measured §2.8 byte ledger it
+               shares with ``repro.sim``'s ``CohortEngine.run_traffic``
   persist    — ServerPersistence: append-only ingest journal + atomic
                periodic snapshots of the full durable state
 """
@@ -27,18 +28,17 @@ from .multitask import MultiTaskTrainer, TaskSpec
 from .persist import ServerPersistence
 from .registry import (MIGRATION_POLICIES, CodebookRegistry,
                        MigrationWindow)
-from .runtime import (AsyncCodeServer, BulkDecodePolicy,
-                      ContinuousIngestService, RoundStats, TickStats,
+from .runtime import (BulkDecodePolicy, ContinuousIngestService, TickStats,
                       UplinkQueue)
 from .scheduler import (STANDARD_SCENARIOS, DiurnalProfile, RoundEvent,
                         RoundScheduler, Scenario, SchedulerConfig)
 from .store import CodeStore, ShardedCodeStore, StoreRecord
 
-__all__ = ["AdmissionResult", "AsyncCodeServer", "BulkDecodePolicy",
+__all__ = ["AdmissionResult", "BulkDecodePolicy",
            "CodePayload", "CodeStore", "CodebookRegistry",
            "ContinuousIngestService", "DiurnalProfile",
            "MIGRATION_POLICIES", "MigrationWindow", "MultiTaskTrainer",
-           "OctopusServer", "RoundEvent", "RoundScheduler", "RoundStats",
+           "OctopusServer", "RoundEvent", "RoundScheduler",
            "STANDARD_SCENARIOS", "Scenario", "SchedulerConfig",
            "ServerPersistence", "ShardedCodeStore", "StoreRecord",
            "TaskSpec", "TickStats", "UplinkQueue"]

@@ -1,32 +1,20 @@
-"""Asynchronous code-server runtime (Step 6 as a first-class subsystem).
+"""Continuous-ingest code-server runtime (Step 6 as a first-class
+subsystem).
 
-``AsyncCodeServer`` owns the server side of the protocol under realistic
-traffic: a fixed slot array of clients (stacked ``ClientState``), a
-``RoundScheduler`` deciding who participates / straggles / churns, a
-``CodebookRegistry`` pinning every merged dictionary, and a ``CodeStore``
-absorbing the uplinks. Per round it
+``ContinuousIngestService`` owns the server side of the protocol under
+realistic traffic: clients ``offer`` uplink payloads whenever they like,
+admission control answers each one with a structured verdict, and a
+clock ``tick`` drains the due slice of the shared :class:`UplinkQueue`
+through the single wire endpoint (``OctopusServer.ingest``, keyed on the
+payload's own codebook version) into the store, then runs the
+background bulk decoder. Merges and rolling codebook migrations go
+through the service so they journal; ``recover`` rebuilds a killed
+service from its snapshot + journal.
 
-  1. applies churn — (re-)joining slots deploy fresh from the CURRENT
-     server and adopt the latest codebook version; leavers go dark with
-     whatever stale state they had,
-  2. advances the participant subset through ONE jitted engine call
-     (``SimEngine.round_indices``) and scatters the states back,
-  3. splits the participants into delivery groups by (codebook version,
-     straggler delay, dropped) and bit-packs each group's codes into its
-     own measured ``repro.wire.CodePayload`` — version and label
-     channels travel INSIDE the carrier, so stragglers' packets stay
-     tagged with the dictionary they were packed under,
-  4. delivers every in-flight payload whose arrival round has come
-     through the single wire endpoint (``OctopusServer.ingest``, keyed
-     on the payload's own version; dropped packets burn uplink bytes but
-     never land),
-  5. every ``merge_every`` rounds runs the staleness-weighted Step 5
-     merge over the ACTIVE population — slots that never got sampled
-     since their last deploy still sit on an older dictionary version,
-     so their contribution is discounted by ``staleness_decay ** lag`` —
-     registers the new dictionary version, and (optionally) re-deploys
-     the slots that actually participated since the last merge (only
-     they synced; everyone else keeps lagging until sampled or churned).
+The population side that feeds it lives in ``repro.sim``:
+``CohortEngine.run_traffic`` streams scheduler-driven rounds through the
+same ``UplinkQueue`` into an ``OctopusServer``, and
+``CohortEngine.run_continuous`` offers cohorts to this service.
 
 Downstream, ``MultiTaskTrainer`` trains any number of heads from one
 bulk decode of the store — see repro.server.multitask.
@@ -40,14 +28,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import octopus as OC
 from repro.obs import recorder as _obs
-from repro.sim.engine import SimEngine
 from repro.wire import CodePayload, OctopusServer
-
-from .registry import CodebookRegistry
-from .scheduler import RoundEvent, RoundScheduler
-from .store import CodeStore
 
 
 class PendingUplink(NamedTuple):
@@ -63,8 +45,8 @@ class PendingUplink(NamedTuple):
 class UplinkQueue:
     """In-flight uplink payloads + the measured byte ledger (§2.8).
 
-    Shared by :class:`AsyncCodeServer` and the cohort traffic driver
-    (``repro.sim.cohort.CohortEngine.run_traffic``): ``send`` charges
+    Shared by :class:`ContinuousIngestService` and the cohort traffic
+    driver (``repro.sim.cohort.CohortEngine.run_traffic``): ``send`` charges
     every payload's MEASURED ``nbytes`` to the uplink (dropped packets
     burn bytes but never land); ``deliver`` pushes everything whose
     arrival round has come through the wire endpoint.
@@ -189,17 +171,6 @@ class UplinkQueue:
 
     def __len__(self) -> int:
         return len(self._pending)
-
-
-class RoundStats(NamedTuple):
-    round: int
-    n_participants: int
-    n_joined: int
-    n_left: int
-    bytes_sent: int          # measured, incl. packets that will drop
-    bytes_delivered: int     # measured, landed in the store this round
-    n_delivered: int         # delivery groups landed this round
-    merged_version: Optional[int]   # registry version if this round merged
 
 
 class BulkDecodePolicy(NamedTuple):
@@ -635,201 +606,3 @@ class ContinuousIngestService:
     @property
     def n_deferred(self) -> int:
         return self.verdicts.get("deferred", 0)
-
-
-class AsyncCodeServer:
-    """Server runtime: scheduler-driven rounds over a versioned store.
-
-    Since the continuous-ingest refactor this is a thin round-quantized
-    shim over :class:`ContinuousIngestService` — each ``run_round`` is
-    exactly one service tick (offer the round's delivery groups, tick
-    the clock, merge on schedule). The background bulk decoder is OFF
-    here (``interval_ticks=0``): the round driver decodes when its
-    trainer asks, like it always did.
-    """
-
-    def __init__(self, engine: SimEngine, server: OC.ServerState,
-                 scheduler: RoundScheduler, *,
-                 store: Optional[CodeStore] = None,
-                 registry: Optional[CodebookRegistry] = None,
-                 merge_every: int = 0, staleness_decay: float = 0.5,
-                 redeploy_on_merge: bool = True):
-        self.engine = engine
-        self.scheduler = scheduler
-        self.n_slots = scheduler.n_slots
-        # ONE wire endpoint owns server state + registry + store: ingest
-        # is keyed on each payload's own codebook version
-        self.wire = OctopusServer(server, engine.cfg, store=store,
-                                  registry=registry)
-        self.merge_every = merge_every
-        self.staleness_decay = staleness_decay
-        self.redeploy_on_merge = redeploy_on_merge
-
-        self.clients = engine.init_clients(server, self.n_slots)
-        self.slot_versions = np.full(self.n_slots, self.registry.latest,
-                                     dtype=int)
-        self._participated = np.zeros(self.n_slots, dtype=bool)
-        # the round loop is one service tick per round (no background
-        # decode, no admission capacity — the legacy contract)
-        self.service = ContinuousIngestService(
-            self.wire, decode_policy=BulkDecodePolicy(interval_ticks=0))
-        self.queue = self.service.queue
-        self.n_merges = 0
-
-    @property
-    def round(self) -> int:
-        return self.service.tick_idx
-
-    # --------------------------------------------- wire endpoint delegates
-
-    @property
-    def server(self) -> OC.ServerState:
-        return self.wire.state
-
-    @property
-    def registry(self) -> CodebookRegistry:
-        return self.wire.registry
-
-    @property
-    def store(self) -> CodeStore:
-        return self.wire.store
-
-    # byte ledger lives on the shared UplinkQueue
-
-    @property
-    def bytes_sent(self) -> int:
-        return self.queue.bytes_sent
-
-    @property
-    def bytes_delivered(self) -> int:
-        return self.queue.bytes_delivered
-
-    @property
-    def bytes_dropped(self) -> int:
-        return self.queue.bytes_dropped
-
-    # ------------------------------------------------------------ helpers
-
-    def _set_slots(self, ids: np.ndarray, sub: OC.ClientState) -> None:
-        self.clients = jax.tree.map(
-            lambda full, part: full.at[jnp.asarray(ids)].set(part),
-            self.clients, sub)
-
-    def _deploy_fresh(self, ids: np.ndarray) -> None:
-        """(Re-)deploy slots from the CURRENT server (Step 2 for joiners)."""
-        if ids.size == 0:
-            return
-        fresh = OC.client_init(self.server)
-        self.clients = jax.tree.map(
-            lambda full, leaf: full.at[jnp.asarray(ids)].set(leaf),
-            self.clients, fresh)
-        self.slot_versions[ids] = self.registry.latest
-
-    # -------------------------------------------------------------- round
-
-    def run_round(self, data, labels=None) -> RoundStats:
-        """One scheduler-driven round.
-
-        data: (n_slots, B, ...) — every slot's would-be local batch (only
-        participants' rows are touched). labels: optional per-task dict
-        (or bare array) of (n_slots, B) arrays riding with the uplink.
-        """
-        assert data.shape[0] == self.n_slots, (data.shape, self.n_slots)
-        rec = _obs.active()
-        t0 = time.perf_counter() if rec is not None else 0.0
-        ev: RoundEvent = self.scheduler.step()
-        self._deploy_fresh(ev.joined)
-
-        ids = ev.participants
-        jids = jnp.asarray(ids)
-        sub = jax.tree.map(lambda x: x[jids], self.clients)
-        sub, idx = self.engine.round_indices(sub, data[jids])
-        self._set_slots(ids, sub)
-        self._participated[ids] = True
-
-        label_dict = None
-        if labels is not None:
-            label_dict = labels if isinstance(labels, dict) \
-                else {"label": labels}
-
-        # ---- split into delivery groups: (version, delay, dropped); each
-        # group's payload carries ITS version + label channels, so the
-        # store keys ingestion off the carrier alone
-        sent = 0
-        versions = self.slot_versions[ids]
-        groups: Dict[tuple, list] = {}
-        for j in range(ids.size):
-            k = (int(versions[j]), int(ev.delays[j]), bool(ev.dropped[j]))
-            groups.setdefault(k, []).append(j)
-        for (version, delay, dropped), pos in groups.items():
-            pos = np.asarray(pos)
-            gidx = idx[jnp.asarray(pos)]
-            glabels = None
-            if label_dict is not None:
-                grows = jnp.asarray(ids[pos])
-                glabels = {t: y[grows].reshape(-1)
-                           for t, y in label_dict.items()}
-            packed = CodePayload.pack(gidx, bits=self.engine.bits,
-                                      version=version, labels=glabels)
-            res = self.service.offer(packed, client_ids=ids[pos],
-                                     delay=delay, dropped=dropped)
-            sent += res.nbytes
-
-        # ---- low-frequency Step 5 merge over the ACTIVE population:
-        # decided BEFORE the tick so the round event carries it
-        this_round = self.round
-        merged_version = None
-        if self.merge_every and (this_round + 1) % self.merge_every == 0:
-            merged_version = self._merge()
-
-        # ---- one service tick: deliver everything whose arrival round
-        # has come through the single wire endpoint (version/labels read
-        # from the payload)
-        ts = self.service.tick(merged_version=merged_version,
-                               emit_event=False)
-        delivered, n_del = ts.bytes_delivered, ts.n_delivered
-
-        stats = RoundStats(round=this_round, n_participants=ids.size,
-                           n_joined=ev.joined.size, n_left=ev.left.size,
-                           bytes_sent=sent, bytes_delivered=delivered,
-                           n_delivered=n_del, merged_version=merged_version)
-        if rec is not None:
-            dur_ms = (time.perf_counter() - t0) * 1e3
-            rec.event("round", round=this_round,
-                      n_participants=int(ids.size),
-                      n_joined=int(ev.joined.size),
-                      n_left=int(ev.left.size), bytes_sent=sent,
-                      bytes_delivered=delivered,
-                      queue_depth=len(self.queue),
-                      bytes_in_flight=self.queue.bytes_in_flight,
-                      merged_version=merged_version, dur_ms=dur_ms)
-        return stats
-
-    def _merge(self) -> int:
-        act = np.nonzero(self.scheduler.active)[0]
-        jact = jnp.asarray(act)
-        version = self.wire.merge(
-            self.clients.params["codebook"][jact],
-            self.clients.ema.counts[jact],
-            client_versions=self.slot_versions[act],
-            staleness_decay=self.staleness_decay)
-        self.n_merges += 1
-        if self.redeploy_on_merge:
-            # only slots that participated since the last merge synced;
-            # everyone else keeps their stale deployment (and version),
-            # so the NEXT merge discounts them by staleness_decay ** lag
-            self._deploy_fresh(np.nonzero(self._participated
-                                          & self.scheduler.active)[0])
-        self._participated[:] = False
-        return version
-
-    # ---------------------------------------------------------- downstream
-
-    def dataset(self, version=None):
-        """Version-correct bulk decode of everything delivered so far
-        (``OctopusServer.features``)."""
-        return self.wire.features(version=version)
-
-    @property
-    def in_flight(self) -> int:
-        return len(self.queue)

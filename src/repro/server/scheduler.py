@@ -5,10 +5,10 @@ deployments decide that set adversarially — a fraction of the population
 participates per round, some uplinks arrive rounds late (stragglers),
 some never arrive (radio loss), and the population itself churns as
 devices enroll and disappear. ``RoundScheduler`` turns those knobs into
-a deterministic per-round event stream that the async code server
-replays through ``SimEngine``, so every scenario — full participation,
-25 % + stragglers, churn with codebook-version lag — runs through the
-SAME jitted population round.
+a deterministic per-round event stream that ``repro.sim``'s
+``CohortEngine`` replays in cohorts, so every scenario — full
+participation, 25 % + stragglers, churn with codebook-version lag — runs
+through the SAME jitted population round.
 
 Determinism: the whole schedule is a pure function of the constructor
 PRNG key. Every per-round draw gets its OWN substream

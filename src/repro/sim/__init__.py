@@ -1,8 +1,10 @@
 """Batched multi-client OCTOPUS simulation (ROADMAP: client populations
 at scale, not one Python object per client).
 
-  engine  — stacked ClientState pytrees + one jitted vmap/shard_map round;
-            the round's uplink is a ``repro.wire.CodePayload``
+  engine  — stacked ClientState pytrees + one jitted vmap/shard_map round
+            program (fine-tune, encode, the fused Steps 3-5 tail) and a
+            one-dispatch deploy; the round's uplink is a
+            ``repro.wire.CodePayload``
   cohort  — cohort-streamed population rounds (100k+ clients): fixed-size
             cohorts through ONE compiled engine round, exactly
             associative Step-5 stats merge, scheduler-driven traffic +

@@ -12,14 +12,18 @@ population to advance per device call, so this engine:
   * optionally wraps the vmap in ``shard_map`` over the mesh 'data' axis
     so client shards advance on separate devices (the same mesh contract
     as repro.distributed.sharding),
-  * finishes Steps 3-5 in ONE fused quantize-pack-stats dispatch
-    (repro.kernels.encode_codes): every client's latents are matched
-    against that client's OWN codebook, bit-packed into a per-client
-    dense uint32 record stream, and reduced to the EMA statistics that
-    complete the Step 5 refresh — the population's (N, K) distance
-    matrix and int32 index tensor never exist, and the per-round uplink
-    bytes are MEASURED from the buffers that would actually cross the
-    network, per-client padding included (§2.8).
+  * finishes Steps 3-5 with ``octopus.client_uplink``, the one fused
+    tail the session round shares: ONE quantize-pack-stats dispatch
+    (repro.kernels.encode_codes) matches every client's latents against
+    that client's OWN codebook, bit-packs them into a per-client dense
+    uint32 record stream and reduces them to the statistics of the
+    Step 5 EMA refresh — the population's (N, K) distance matrix and
+    int32 index tensor never exist, and the per-round uplink bytes are
+    MEASURED from the buffers that would actually cross the network,
+    per-client padding included (§2.8).
+
+The engine compiles that round and the deploy below, nothing else; the
+unfused ``octopus.client_round`` is the tests' reference for it.
 
 Step 2's deployment of a population is ONE compiled dispatch too
 (``SimEngine.init_clients``): every leaf of the fresh ``ClientState`` is
@@ -43,6 +47,7 @@ import jax.numpy as jnp
 from repro.core import octopus as OC
 from repro.core.dvqae import DVQAEConfig
 from repro.wire.payload import CodePayload, normalize_labels
+from repro.wire.session import index_shape
 
 
 def __getattr__(name):
@@ -101,10 +106,6 @@ class SimEngine:
         self.bits = OC.transmit_bits(cfg)
         self.mesh = mesh
 
-        def one_client(client, batch):
-            return OC.client_round(client, cfg, batch, lr=lr, gamma=gamma,
-                                   n_local_steps=n_local_steps)
-
         def one_client_encode(client, batch):
             """Steps 2-3 front half (the same code path client_round
             runs), latents flattened to (P, M) for the fused dispatch."""
@@ -112,28 +113,13 @@ class SimEngine:
                 client, cfg, batch, lr=lr, n_local_steps=n_local_steps)
             return client, z.reshape(-1, z.shape[-1])
 
-        step = jax.vmap(one_client)
-        bits = self.bits
-
         def _round(clients, data):
-            """One vmapped encode + ONE fused quantize-pack-stats dispatch
-            for the (per-shard) population: the kernel quantizes every
-            client's latents against that client's own codebook, emits
-            each client's packed uplink record, and hands back the
-            per-client EMA statistics that complete Step 5 without a
-            second network pass."""
-            from repro.core.ema import ema_update_from_stats
-            from repro.kernels.ops import encode_codes
+            """One vmapped encode, then the fused Steps 3-5 tail for the
+            (per-shard) population: ONE quantize-pack-stats dispatch
+            against every client's own codebook and the EMA refresh from
+            its statistics, without a second network pass."""
             clients, z = jax.vmap(one_client_encode)(clients, data)
-            payload, counts, sums = encode_codes(
-                z, clients.params["codebook"], bits=bits,
-                n_groups=cfg.n_groups, n_slices=cfg.n_slices)
-            ema = ema_update_from_stats(clients.ema, counts, sums,
-                                        gamma=gamma)
-            params = {**clients.params, "codebook": ema.codebook}
-            clients = OC.ClientState(params=params, ema=ema,
-                                     step=clients.step)
-            return clients, payload
+            return OC.client_uplink(clients, cfg, z, gamma=gamma)
 
         def _deploy(params, n_clients):
             # the deploy reads only the server's params, never its AdamW
@@ -151,8 +137,6 @@ class SimEngine:
             # fresh clients land sharded over 'data', as the round reads
             # them: no reshard before each cohort's round
             deploy_kw["out_shardings"] = NamedSharding(mesh, spec)
-            step = jax.shard_map(step, mesh=mesh, in_specs=(spec, spec),
-                                 out_specs=(spec, spec), check_vma=False)
             # the WHOLE round — encode, fused dispatch, EMA — runs inside
             # the shard-mapped body, so the kernel sees only its shard's
             # clients; per-shard payloads are per-client record streams,
@@ -162,8 +146,6 @@ class SimEngine:
                                      out_specs=(spec, spec), check_vma=False)
             self._uplink_sharding = NamedSharding(
                 Mesh(mesh.devices.flat[:1], ("uplink",)), P())
-        self._step = step
-        self._step_jit = jax.jit(step)
         self._round = jax.jit(round_fn)
         self._deploy = jax.jit(_deploy, static_argnums=1, **deploy_kw)
         self._shape_cache = {}
@@ -209,27 +191,18 @@ class SimEngine:
             labels=normalize_labels(labels, c * int(data.shape[1])),
             privatized=True)
 
-    def round_indices(self, clients: OC.ClientState, data
-                      ) -> Tuple[OC.ClientState, jax.Array]:
-        """Steps 2-5 for the (sub)population, returning the UNPACKED int32
-        code indices (C, B, T[, n_c]).
-
-        The async code server (repro.server) uses this instead of
-        ``round`` because participants split into delivery groups —
-        stragglers, drops, per-version lanes — and each group packs its
-        own uplink buffer; one population-wide payload would glue them
-        together.
-        """
-        c = client_batch_size(clients)
-        assert data.shape[0] == c, (data.shape, c)
-        return self._step_jit(clients, data)
-
     def _index_shape(self, clients, data) -> Tuple[int, ...]:
+        """(C, B, T[, n_slices]) of the round's codes, from the shape of
+        one client's encoder output (traced once per data shape)."""
         cache_key = tuple(data.shape)
         if cache_key not in self._shape_cache:
-            out = jax.eval_shape(lambda c, d: self._step(c, d)[1],
-                                 clients, data)
-            self._shape_cache[cache_key] = tuple(out.shape)
+            def one(x):             # one client's unsharded shape
+                return jax.ShapeDtypeStruct(x.shape[1:], x.dtype)
+            z = jax.eval_shape(
+                lambda p, d: OC.client_encode(p, self.cfg, d)[0],
+                jax.tree.map(one, clients.params), one(data))
+            self._shape_cache[cache_key] = (int(data.shape[0]),) + \
+                index_shape(self.cfg, z.shape)
         return self._shape_cache[cache_key]
 
     # ------------------------------------------------------- server side

@@ -19,11 +19,11 @@ from .payload import (DEFAULT_TASK, SUPPORTED_WIRE_VERSIONS, WIRE_VERSION,
                       normalize_labels, payload_crc)
 from .session import (ADMISSION_VERDICTS, TRANSIENT_REASONS,
                       AdmissionResult, OctopusClient, OctopusServer,
-                      RetryPolicy, fused_round, round_words)
+                      RetryPolicy, round_words)
 
 __all__ = ["ADMISSION_VERDICTS", "AdmissionResult", "CodePayload",
            "OctopusClient", "OctopusServer", "RetryPolicy",
            "SUPPORTED_WIRE_VERSIONS", "TRANSIENT_REASONS", "WIRE_VERSION",
            "DEFAULT_TASK", "as_payload", "concat_payloads",
-           "decode_payloads", "decode_rows", "fused_round",
-           "normalize_labels", "payload_crc", "round_words"]
+           "decode_payloads", "decode_rows", "normalize_labels",
+           "payload_crc", "round_words"]

@@ -26,9 +26,9 @@ These two classes subsume it:
     bytes stay on the §2.8 ledger. Rolling ``v_n -> v_{n+1}`` codebook
     upgrades run through ``begin_migration`` / ``complete_migration``.
 
-The pure, jittable round core is :func:`round_words` — bit-identical to
-the PR-4 ``client_round_fused`` tail (same calls, same dispatch count);
-``SimEngine`` remains the batched population driver for the same wire.
+The pure, jittable round core is :func:`round_words`; its Steps 3-5
+tail is ``octopus.client_uplink``, the same fused tail ``SimEngine``'s
+population round runs for the same wire.
 """
 from __future__ import annotations
 
@@ -118,25 +118,21 @@ class AdmissionResult(NamedTuple):
 def _round_core(client: OC.ClientState, cfg: DVQAEConfig, batch, *,
                 lr: float = 1e-4, gamma: float = 0.99,
                 n_local_steps: int = 1, refresh: bool = True):
-    """Steps 2-5 with the fused uplink tail -> (client, z, words).
+    """Steps 2-5 of one client -> (client, z, words).
 
-    Exactly the ``client_round_fused`` computation: ``n_local_steps`` of
-    frozen-codebook fine-tuning, ONE encoder pass, ONE
-    ``ops.encode_codes`` dispatch (quantize + pack + EMA stats on-chip),
-    optional Step 5 refresh from the precomputed statistics. Neither the
-    (N, K) distance matrix nor the int32 index tensor ever materializes.
+    ``n_local_steps`` of frozen-codebook fine-tuning, ONE encoder pass,
+    then the fused Steps 3-5 tail (``octopus.client_uplink``) on the
+    client lifted to one record: ONE ``ops.encode_codes`` dispatch
+    (quantize + pack + EMA stats on-chip) and the optional Step 5
+    refresh from its statistics. Neither the (N, K) distance matrix nor
+    the int32 index tensor ever materializes.
     """
-    from repro.kernels.ops import encode_codes
     client, z = OC.client_finetune_encode(client, cfg, batch, lr=lr,
                                           n_local_steps=n_local_steps)
-    zf = z.reshape(1, -1, z.shape[-1])
-    words, counts, sums = encode_codes(
-        zf, client.params["codebook"][None], bits=OC.transmit_bits(cfg),
-        n_groups=cfg.n_groups, n_slices=cfg.n_slices)
-    if refresh:
-        client = OC.client_codebook_refresh(client, cfg, None, gamma=gamma,
-                                            stats=(counts[0], sums[0]))
-    return client, z, words
+    clients, words = OC.client_uplink(
+        jax.tree.map(lambda x: x[None], client), cfg,
+        z.reshape(1, -1, z.shape[-1]), gamma=gamma, refresh=refresh)
+    return jax.tree.map(lambda x: x[0], clients), z, words
 
 
 def round_words(client: OC.ClientState, cfg: DVQAEConfig, batch, *,
@@ -162,24 +158,6 @@ def index_shape(cfg: DVQAEConfig, z_shape) -> Tuple[int, ...]:
     if cfg.n_groups > 1 or cfg.n_slices > 1:
         return base + (cfg.n_slices,)
     return base
-
-
-def fused_round(client: OC.ClientState, cfg: DVQAEConfig, batch, *,
-                lr: float = 1e-4, gamma: float = 0.99,
-                n_local_steps: int = 1, refresh: bool = True,
-                version: int = 0, labels=None
-                ) -> Tuple[OC.ClientState, CodePayload]:
-    """One client round -> (client, CodePayload). The payload carries the
-    wire's (C, B, ...) leading layout with C == 1 — one record stream,
-    ready for ``OctopusServer.ingest`` / ``CodeStore.add``."""
-    client, z, words = _round_core(client, cfg, batch, lr=lr, gamma=gamma,
-                                   n_local_steps=n_local_steps,
-                                   refresh=refresh)
-    shape = (1,) + index_shape(cfg, z.shape)
-    return client, CodePayload.from_words(
-        words, bits=OC.transmit_bits(cfg), shape=shape, n_records=1,
-        version=version, labels=labels, n_samples=int(z.shape[0]),
-        privatized=True)
 
 
 # ----------------------------------------------------------------- client
@@ -238,10 +216,15 @@ class OctopusClient:
         n_local = self.n_local_steps if finetune is None else int(finetune)
         rec = _obs.active()
         t0 = time.perf_counter() if rec is not None else 0.0
-        self.state, payload = fused_round(
+        self.state, z, words = _round_core(
             self.state, self.cfg, batch, lr=self.lr, gamma=self.gamma,
-            n_local_steps=n_local, refresh=refresh, version=self.version,
-            labels=labels)
+            n_local_steps=n_local, refresh=refresh)
+        # the wire's (C, B, ...) leading layout with C == 1: one record
+        payload = CodePayload.from_words(
+            words, bits=OC.transmit_bits(self.cfg),
+            shape=(1,) + index_shape(self.cfg, z.shape), n_records=1,
+            version=self.version, labels=labels, n_samples=int(z.shape[0]),
+            privatized=True)
         if rec is not None:
             jax.block_until_ready(payload.payload)
             rec.event("encode", dur_ms=(time.perf_counter() - t0) * 1e3,

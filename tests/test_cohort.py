@@ -316,3 +316,53 @@ def test_traffic_run_is_replayable(tiny_cfg, data):
     fa, _ = wa.features()
     fb, _ = wb.features()
     np.testing.assert_array_equal(np.asarray(fa), np.asarray(fb))
+
+
+def test_traffic_churn_versions_and_accounting(tiny_cfg, data):
+    """The churn scenario through run_traffic: records land under >= 2
+    codebook versions, the byte ledger closes, and every record
+    re-decodes bit-exactly against its own version after all merges."""
+    from repro.server import STANDARD_SCENARIOS, RoundScheduler, UplinkQueue
+    n_rounds = 8
+    state = OC.server_init(jax.random.PRNGKey(0), tiny_cfg)
+    wire, queue = OctopusServer(state, tiny_cfg), UplinkQueue()
+    sched = RoundScheduler(N_CLIENTS, STANDARD_SCENARIOS["churn"].sched,
+                           key=jax.random.PRNGKey(3))
+    sent_under = {}             # round -> (version, codebook) it packed under
+
+    def data_fn(ids):
+        sent_under.setdefault(sched.round - 1, (
+            wire.version, np.asarray(wire.registry.current)))
+        return data[np.asarray(ids)]
+
+    def labels_fn(ids):
+        return {"content": jnp.tile(jnp.arange(data.shape[1]),
+                                    (len(ids), 1))}
+
+    engine = CohortEngine(tiny_cfg, gamma=0.9, n_local_steps=0)
+    hist = engine.run_traffic(wire, sched, data_fn, labels_fn=labels_fn,
+                              cohort_size=4, n_rounds=n_rounds,
+                              merge_every=2, queue=queue)
+
+    merged = [h.merged_version for h in hist if h.merged_version is not None]
+    assert merged == list(range(1, n_rounds // 2 + 1))
+    assert wire.registry.latest == len(merged)
+    assert queue.bytes_sent == (queue.bytes_delivered + queue.bytes_dropped
+                                + queue.bytes_in_flight)
+    assert queue.bytes_rejected == queue.bytes_duplicate == 0
+    records = wire.store.records
+    assert len({rec.version for rec in records}) >= 2  # stragglers lag
+
+    for rec in records:
+        version, codebook = sent_under[rec.round]
+        assert rec.version == version
+        codes = rec.packed.unpack().reshape((-1,) + rec.packed.shape[2:])
+        now = OC.codes_to_features(None, tiny_cfg, codes,
+                                   codebook=wire.registry.get(rec.version))
+        then = OC.codes_to_features(None, tiny_cfg, codes,
+                                    codebook=jnp.asarray(codebook))
+        np.testing.assert_array_equal(np.asarray(now), np.asarray(then))
+
+    feats, got = wire.features()
+    assert feats.shape[0] == wire.store.n_samples
+    assert got["content"].shape[0] == wire.store.n_samples

@@ -9,8 +9,6 @@ What makes the clocked service a subsystem and not a queue wrapper:
     payloads of BOTH versions, and decode stays bit-identical to
     decoding each payload against its pinned registry snapshot — under
     every policy (keep / retire / reencode);
-  * the round-driven ``AsyncCodeServer`` is a thin shim over the
-    service (one tick per round) with unchanged behaviour;
   * open-ended Poisson traffic (``SchedulerConfig.rate``) is
     deterministic under one PRNG key, quiet ticks included.
 """
@@ -332,28 +330,3 @@ def test_poisson_arrivals_deterministic_and_bursty():
                        key=jax.random.PRNGKey(2))
     kc = [c.step().participants.size for _ in range(20)]
     assert kc == ka
-
-
-# ---------------------------------------------------------- legacy shim
-
-def test_async_server_is_a_thin_shim_over_the_service(tiny_cfg, data):
-    """AsyncCodeServer.run_round == one service tick: the service's
-    clock, queue and ledger ARE the legacy attributes."""
-    from repro.server import AsyncCodeServer
-    from repro.sim import SimEngine
-    state = OC.server_init(jax.random.PRNGKey(0), tiny_cfg)
-    sched = RoundScheduler(N_CLIENTS,
-                           SchedulerConfig(participation=0.5,
-                                           straggler_prob=0.4),
-                           key=jax.random.PRNGKey(11))
-    acs = AsyncCodeServer(SimEngine(tiny_cfg, gamma=0.9, n_local_steps=0),
-                          state, sched, merge_every=2)
-    assert acs.queue is acs.service.queue
-    for r in range(3):
-        assert acs.round == r == acs.service.tick_idx
-        stats = acs.run_round(data)
-        assert stats.round == r
-    assert acs.bytes_sent == acs.service.queue.bytes_sent
-    assert acs.bytes_sent == acs.bytes_delivered + acs.bytes_dropped + \
-        acs.queue.bytes_rejected + acs.queue.bytes_duplicate + \
-        acs.queue.bytes_in_flight

@@ -1,4 +1,5 @@
-"""Async code-server runtime (repro.server).
+"""Code-server subsystem (repro.server): store, registry, scheduler,
+heads.
 
 The contracts that make Step 6 a subsystem instead of a buffer:
   * CodeStore bounds memory (FIFO/reservoir eviction) and decodes each
@@ -21,10 +22,8 @@ from repro.core import downstream as DS
 from repro.core import octopus as OC
 from repro.core.dvqae import DVQAEConfig
 from repro.kernels.pack_bits import code_bits
-from repro.server import (STANDARD_SCENARIOS, AsyncCodeServer, CodeStore,
-                          CodebookRegistry, MultiTaskTrainer, RoundScheduler,
-                          SchedulerConfig, TaskSpec)
-from repro.sim import SimEngine
+from repro.server import (CodeStore, CodebookRegistry, MultiTaskTrainer,
+                          RoundScheduler, SchedulerConfig, TaskSpec)
 from repro.wire import CodePayload
 
 
@@ -288,71 +287,6 @@ def test_multitask_trains_all_heads_from_shared_features(key):
     assert acc["a"] > 0.9 and acc["b"] > 0.9
     with pytest.raises(ValueError, match="missing"):
         trainer.fit(key, feats, {"a": y1}, steps=1)
-
-
-# ----------------------------------------------------------------- runtime
-
-def test_async_runtime_churn_versions_and_accounting(tiny_cfg, server, key):
-    """End-to-end churn scenario: version lag lands in the store, byte
-    accounting closes, and stored records re-decode bit-exactly against
-    their own version after multiple merges."""
-    n_slots, b, rounds = 8, 2, 8
-    engine = SimEngine(tiny_cfg, gamma=0.9, n_local_steps=0)
-    sched = RoundScheduler(n_slots, STANDARD_SCENARIOS["churn"].sched,
-                           key=jax.random.PRNGKey(3))
-    srv = AsyncCodeServer(engine, server, sched, merge_every=2,
-                          staleness_decay=0.5)
-    data = jax.random.normal(key, (n_slots, b, 8, 8, 3))
-    labels = {"content": jnp.tile(jnp.arange(b), (n_slots, 1))}
-
-    refs = []
-    for r in range(rounds):
-        srv.run_round(data, labels=labels)
-        for rec in srv.store.records[len(refs):]:
-            codes = rec.packed.unpack().reshape((-1,) + rec.packed.shape[2:])
-            refs.append(np.asarray(OC.codes_to_features(
-                None, tiny_cfg, codes,
-                codebook=srv.registry.get(rec.version))))
-
-    assert srv.n_merges == rounds // 2 >= 2
-    assert srv.registry.latest == srv.n_merges
-    assert srv.bytes_sent == (srv.bytes_delivered + srv.bytes_dropped
-                              + srv.queue.bytes_in_flight)
-    versions = {rec.version for rec in srv.store.records}
-    assert len(versions) >= 2          # stragglers/re-joiners really lag
-
-    # bit-exact per-version decode after all merges (tentpole acceptance)
-    for rec, ref in zip(srv.store.records, refs):
-        codes = rec.packed.unpack().reshape((-1,) + rec.packed.shape[2:])
-        now = OC.codes_to_features(None, tiny_cfg, codes,
-                                   codebook=srv.registry.get(rec.version))
-        np.testing.assert_array_equal(np.asarray(now), ref)
-
-    feats, got = srv.dataset()
-    assert feats.shape[0] == srv.store.n_samples
-    assert got["content"].shape[0] == srv.store.n_samples
-
-
-def test_async_runtime_full_participation_matches_engine_round(tiny_cfg,
-                                                               server, key):
-    """With no churn/stragglers/merges, the runtime's round IS the plain
-    engine round: same client states, same codes in the store."""
-    n_slots, b = 4, 2
-    data = jax.random.normal(key, (n_slots, b, 8, 8, 3))
-    engine = SimEngine(tiny_cfg, gamma=0.9)
-    sched = RoundScheduler(n_slots, SchedulerConfig(),
-                           key=jax.random.PRNGKey(0))
-    srv = AsyncCodeServer(engine, server, sched, merge_every=0)
-    srv.run_round(data)
-
-    clients, packed = engine.round(engine.init_clients(server, n_slots),
-                                   data)
-    np.testing.assert_array_equal(np.asarray(srv.store.codes()),
-                                  np.asarray(packed.unpack()).reshape(
-                                      (-1,) + packed.shape[2:]))
-    jax.tree.map(lambda a, b: np.testing.assert_allclose(
-        np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6),
-        srv.clients, clients)
 
 
 # ------------------------------------------------------------- tombstones

@@ -120,6 +120,32 @@ def test_engine_round_matches_client_loop(tiny_cfg, server, key):
                         rtol=1e-4, atol=3e-4)
 
 
+@pytest.mark.parametrize("quantizer", ["vq", "gsvq"])
+@pytest.mark.parametrize("kind", ["image", "speech"])
+def test_engine_payload_shape_and_codes_match_client_round(kind, quantizer,
+                                                           key):
+    """The round stamps its payload's index shape from the encoder's
+    latent shape alone: (C,) + client_round's index shape, for both
+    modalities and both quantizers, and the codes are client_round's."""
+    widths = (dict(latent_dim=16, codebook_size=64, n_groups=4, n_slices=2)
+              if quantizer == "gsvq" else
+              dict(latent_dim=8, codebook_size=16))
+    image = kind == "image"
+    cfg = DVQAEConfig(kind=kind, in_channels=3 if image else 1, hidden=8,
+                      n_res_blocks=1, **widths)
+    n_clients = 2
+    data = jax.random.normal(
+        key, (n_clients, 2) + ((8, 8, 3) if image else (32, 1)))
+    srv = OC.server_init(key, cfg)
+    engine = SimEngine(cfg, lr=1e-4, gamma=0.9)
+    _, packed = engine.round(engine.init_clients(srv, n_clients), data)
+    idxs = [OC.client_round(OC.client_init(srv), cfg, data[i], lr=1e-4,
+                            gamma=0.9)[1] for i in range(n_clients)]
+    assert packed.shape == (n_clients,) + idxs[0].shape
+    np.testing.assert_array_equal(np.asarray(packed.unpack()),
+                                  np.asarray(jnp.stack(idxs)))
+
+
 def test_engine_sharded_matches_unsharded(tiny_cfg, server, key):
     """shard_map over the mesh 'data' axis == plain vmap."""
     from repro.launch.mesh import make_host_mesh
