@@ -172,6 +172,73 @@ def test_encode_kernel_block_sweep(key):
                                        atol=1e-5)
 
 
+# (n_groups, n_slices, K, M): the benchmark cells' quantizer (3-bit codes),
+# a 3-slice phase pattern (4-bit), and 4,096 single-atom groups (12-bit)
+_CELL_Q = (8, 4, 256, 64)
+_THREE_SLICE_Q = (16, 3, 64, 24)
+_WIDE_Q = (4096, 2, 4096, 8)
+
+
+@pytest.mark.parametrize("quant,block_n,block_k", [
+    (_CELL_Q, 32, 32), (_CELL_Q, 128, 64), (_CELL_Q, 512, 512),
+    (_CELL_Q, 4096, 64), (_THREE_SLICE_Q, 24, 4), (_THREE_SLICE_Q, 96, 16),
+    (_THREE_SLICE_Q, 480, 128), (_THREE_SLICE_Q, 4096, 8),
+    (_CELL_Q, 256, 96), (_THREE_SLICE_Q, 48, 12),   # last K tile padded
+    (_WIDE_Q, 64, 512),         # each slice's 4,096 atoms span 8 K tiles
+], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else str(v))
+def test_encode_kernel_block_sweep_gsvq(key, quant, block_n, block_k):
+    """GSVQ words == the oracle's at every tiling, with block_k below and
+    above each slice's K: each code is matched against its own slice's
+    atoms, K tile by K tile, and lands at its flat (position, slice) row."""
+    n_groups, n_slices, K, M = quant
+    bits = code_bits(n_groups)
+    P = 23 if K > 1024 else 75                   # P*S not a whole block
+    k1, k2 = jax.random.split(jax.random.fold_in(key, block_n + block_k))
+    z = _rand(k1, (2, P, M))
+    cb = _rand(k2, (2, K, M))
+    got = ops.encode_codes(z, cb, bits=bits, n_groups=n_groups,
+                           n_slices=n_slices, block_n=block_n,
+                           block_k=block_k, use_ref=False)
+    want = ref.encode_codes_ref(z, cb, bits=bits, n_groups=n_groups,
+                                n_slices=n_slices)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    np.testing.assert_allclose(np.asarray(got[2]), np.asarray(want[2]),
+                               rtol=1e-5, atol=1e-5)
+
+
+def _pallas_grids(jaxpr):
+    """The grid of every ``pallas_call`` in ``jaxpr``, nested ones too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield tuple(eqn.params["grid_mapping"].grid)
+        for v in eqn.params.values():
+            if hasattr(v, "jaxpr"):
+                yield from _pallas_grids(v.jaxpr)
+
+
+@pytest.mark.parametrize("R,P", [(16, 8192), (2, 128000)],
+                         ids=["image", "speech"])
+@pytest.mark.parametrize("mode", ["gsvq", "vq"])
+def test_encode_tiling_matches_each_code_against_k_rows(R, P, mode):
+    """At both cells' record shapes (K = 256 atoms of M = 64), a code is
+    matched against K table rows in either mode; the slice-stacked GSVQ
+    match gave S*K = 1,024. The kernel runs the tiling's grid."""
+    from repro.kernels.encode_codes import encode_codes_pallas, encode_tiling
+    K, M = 256, 64
+    kw = (dict(bits=3, n_groups=8, n_slices=4) if mode == "gsvq"
+          else dict(bits=8))
+    t = encode_tiling(R, P, K, **kw)
+    S = kw.get("n_slices", 1)
+    assert t.rows_per_code == K
+    assert t.block_p * S == t.block_n
+    assert t.grid[:2] == (R, -(-P * S // t.block_n))
+    jaxpr = jax.make_jaxpr(lambda z, cb: encode_codes_pallas(z, cb, **kw))(
+        jax.ShapeDtypeStruct((R, P, M), jnp.float32),
+        jax.ShapeDtypeStruct((R, K, M), jnp.float32))
+    assert list(_pallas_grids(jaxpr.jaxpr)) == [t.grid]
+
+
 # ---------------------------------------------------------------- protocol
 
 def _count_encoder_passes(fn):

@@ -71,6 +71,18 @@ def test_encode_codes_compiles(one_chip, mode):
         _spec(one_chip, (R, P, M)), _spec(one_chip, (R, K, M)))
 
 
+def test_encode_codes_compiles_speech_records(one_chip):
+    """GSVQ encode at the speech cell's shape: 2 records of 128,000
+    positions, 512,000 codes each."""
+    from repro.kernels.encode_codes import encode_codes_pallas
+    n_groups, n_slices = GSVQ
+    _assert_kernel(
+        lambda z, cb: encode_codes_pallas(z, cb, bits=code_bits(n_groups),
+                                          n_groups=n_groups,
+                                          n_slices=n_slices),
+        _spec(one_chip, (2, 128_000, M)), _spec(one_chip, (2, K, M)))
+
+
 def test_vq_nearest_compiles(one_chip):
     from repro.kernels.vq_nn import vq_nearest_pallas
     _assert_kernel(vq_nearest_pallas, _spec(one_chip, (R * P, M)),
